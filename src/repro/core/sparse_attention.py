@@ -352,9 +352,9 @@ def _xla_gather_executor(q, k, v, sel, *, policy, scale, indices, slot_mask,
 
 def _pallas_executor(q, k, v, sel, *, policy, scale, indices, slot_mask,
                      live_counts, dedup, **_):
-    from repro.kernels import ops as kernel_ops  # deferred: optional dep
+    from repro.kernels import block_sparse_attn  # deferred: optional dep
 
-    return kernel_ops.block_sparse_attention(
+    return block_sparse_attn.block_sparse_attention(
         q, k, v, indices, slot_mask,
         block_size=policy.block_size, scale=scale, group_dedup=dedup,
         live_counts=live_counts)

@@ -44,7 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 # No import cycle: repro.core.selection depends only on jax/numpy, and
 # repro.core.sparse_attention defers its kernels import to call time.
 from repro.core.selection import revisit_indices
-from repro.kernels import pltpu_compat
+from repro.backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -129,7 +129,7 @@ def block_sparse_attention(
     *,
     block_size: int = 128,
     scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
     group_dedup: bool = False,
     live_counts: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
@@ -216,10 +216,10 @@ def block_sparse_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * sel_heads, group, n, dv), q.dtype),
-        compiler_params=pltpu_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="stem_block_sparse_attention",
     )(idx, cnt, qr, k, v)
     return out.reshape(b, sel_heads * group, n, dv)

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pltpu_compat
+from repro.backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -96,7 +96,7 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Causal flash attention.  q: (b, hq, n, d); k, v: (b, hk, n, d)."""
     b, hq, n, d = q.shape
@@ -143,10 +143,10 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=pltpu_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="dense_flash_attention",
     )(qr, k, v)
     return out.reshape(b, hq, n, dv)

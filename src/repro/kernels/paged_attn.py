@@ -48,8 +48,9 @@ pooled queries) and ``StreamingMetric`` (content-free zeros, no kernel
 needed).  Policies with custom metric classes fall back to the XLA oracle
 wholesale, so registering ``executor="pallas"`` is always safe.
 
-``interpret=True`` (the CI default on CPU) runs the identical kernel bodies
-in Python; flip ``INTERPRET`` on real TPU hardware.
+``interpret=None`` (every entry point's default) leaves the mode to the
+backend (``repro.backend.interpret_kernels``): the Pallas interpreter on the
+CPU test backend, compiled Mosaic kernels on TPU.
 """
 from __future__ import annotations
 
@@ -64,12 +65,9 @@ from repro.core import chunked as chunked_lib
 from repro.core import metric as metric_lib
 from repro.core import policy as policy_lib
 from repro.core.selection import revisit_indices
-from repro.kernels import pltpu_compat
+from repro.backend import resolve_interpret
 
 NEG_INF = -1e30
-
-# Flip to False on real TPU hardware (launch scripts do this via env).
-INTERPRET = True
 
 # Process-wide tally of silent XLA fallbacks, keyed by call site
 # ("decode" / "chunk").  Fallbacks fire at TRACE time (once per engine
@@ -90,10 +88,6 @@ def _note_fallback(site: str, reason: str) -> None:
             f"fused_paged_{site}: falling back to the XLA gather oracle "
             f"({reason}); counted in engine.stats['pallas_fallbacks']",
             RuntimeWarning, stacklevel=3)
-
-
-def _resolve_interpret(interpret):
-    return INTERPRET if interpret is None else interpret
 
 
 def _metric_kind(metric) -> str | None:
@@ -144,13 +138,27 @@ def _score_kernel(pt_ref, q_ref, kg_ref, o_ref, *, scale):
 
     q tile (1, nc, s, d) holds the row's pooled queries (nc = 1 for decode),
     kg tile (1, 1, s, d) is DMA'd from ``pool.kg[kv_head, page_table[b, p]]``
-    by the index map.  The (1, nc, maxp) output block is revisited across
-    the page axis; each step fills its own column.
+    by the index map.  The (1, nc, maxp) output block stays resident across
+    the page axis; step p selects its column into the whole block, since
+    Mosaic cannot store at a dynamic lane offset.
     """
     p = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)           # (nc, s, d)
-    kg = kg_ref[0, 0].astype(jnp.float32)      # (s, d)
-    o_ref[0, :, p] = jnp.sum(q * kg[None], axis=(1, 2)) * scale
+    nc = q_ref.shape[1]
+    kg = kg_ref[0, 0].astype(jnp.float32)                     # (s, d)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (nc, 1), 0)
+    col = jnp.zeros((nc, 1), jnp.float32)
+    for i in range(nc):
+        qk = q_ref[0, i].astype(jnp.float32) * kg              # (s, d)
+        sc = jnp.sum(jnp.sum(qk, axis=1, keepdims=True), axis=0,
+                     keepdims=True)                            # (1, 1)
+        col = jnp.where(rows == i, sc * scale, col)
+
+    @pl.when(p == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    lanes = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape[1:], 1)
+    o_ref[0] = jnp.where(lanes == p, col, o_ref[0])
 
 
 def _score_pages(qp, kg_pool, page_table, *, group, scale, interpret,
@@ -186,7 +194,7 @@ def _score_pages(qp, kg_pool, page_table, *, group, scale, interpret,
         functools.partial(_score_kernel, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, nc, maxp), jnp.float32),
-        compiler_params=pltpu_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -211,7 +219,7 @@ def decode_page_scores(q, kg_pool, page_table, *, group,
     # sums over all s groups).
     qp = jnp.broadcast_to(q[:, :, :, None, :], (b, hq, 1, s, d))
     out = _score_pages(qp, kg_pool, page_table, group=group, scale=scale,
-                       interpret=_resolve_interpret(interpret),
+                       interpret=resolve_interpret(interpret),
                        name="stem_paged_decode_score")
     return out.reshape(b, hq // group, group, page_table.shape[1])
 
@@ -238,7 +246,7 @@ def chunk_page_scores(q, kg_pool, page_table, *, block_size, pooling,
         qp = jnp.broadcast_to(qp.mean(axis=-2, keepdims=True), qp.shape)
         scale = 1.0 / (s * float(d) ** 0.5)
     return _score_pages(qp, kg_pool, page_table, group=group, scale=scale,
-                        interpret=_resolve_interpret(interpret),
+                        interpret=resolve_interpret(interpret),
                         name="stem_paged_chunk_score")
 
 
@@ -361,7 +369,7 @@ def _attend_pages(q, k_pool, v_pool, gp, idx, cnt, pos, *, block_size,
             heads=hq, causal=causal),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, nc, rows, dv), q.dtype),
-        compiler_params=pltpu_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -394,7 +402,7 @@ def fused_paged_decode(q, pool, page_table, cache_lens, cfg,
         return paged_lib.paged_sparse_decode(
             q, pool, page_table, cache_lens, policy, budget_frac,
             executor="xla")
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
 
     b, hq, _, d = q.shape
     hk = pool.k.shape[0]
@@ -446,7 +454,7 @@ def fused_paged_chunk(q, pool, page_table, chunk_start, budgets, cfg,
         return chunked_lib.chunked_prefill_attention(
             q, pool, page_table, chunk_start, budgets, policy, k_max,
             executor="xla")
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
 
     b, hq, c, d = q.shape
     hk = pool.k.shape[0]

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pltpu_compat
+from repro.backend import resolve_interpret
 
 
 def _pool_kernel(x_ref, o_ref, *, block_size: int, stride: int):
@@ -33,7 +33,7 @@ def _pool_kernel(x_ref, o_ref, *, block_size: int, stride: int):
 
 @functools.partial(jax.jit, static_argnames=("block_size", "stride", "interpret"))
 def antidiag_pool(
-    x: jnp.ndarray, *, block_size: int = 128, stride: int = 16, interpret: bool = True
+    x: jnp.ndarray, *, block_size: int = 128, stride: int = 16, interpret: bool | None = None
 ) -> jnp.ndarray:
     """(b, h, n, d) -> (b, h, n/block, stride, d) group means."""
     b, h, n, d = x.shape
@@ -45,10 +45,10 @@ def antidiag_pool(
         in_specs=[pl.BlockSpec((1, block_size, d), lambda bh, i: (bh, i, 0))],
         out_specs=pl.BlockSpec((1, 1, stride, d), lambda bh, i: (bh, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, nb, stride, d), jnp.float32),
-        compiler_params=pltpu_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="stem_antidiag_pool",
     )(xr)
     return out.reshape(b, h, nb, stride, d)
@@ -56,28 +56,33 @@ def antidiag_pool(
 
 def _vmag_kernel(v_ref, o_ref, *, block_size: int):
     v = v_ref[0, ...].astype(jnp.float32)  # (block, d)
-    norms = jnp.sqrt(jnp.maximum((v * v).sum(axis=-1), 1e-40))
-    o_ref[0, 0, :] = jnp.max(jnp.log(norms))[None]
+    sq = jnp.sum(v * v, axis=-1, keepdims=True)                # (block, 1)
+    m = jnp.max(jnp.log(jnp.sqrt(jnp.maximum(sq, 1e-40))),
+                axis=0, keepdims=True)                         # (1, 1)
+    o_ref[0, 0] = jnp.broadcast_to(m, o_ref.shape[2:])
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "interpret"))
 def value_magnitude(
-    v: jnp.ndarray, *, block_size: int = 128, interpret: bool = True
+    v: jnp.ndarray, *, block_size: int = 128, interpret: bool | None = None
 ) -> jnp.ndarray:
     """(b, h, n, d) -> (b, h, n/block) block-max log ||V_j||_2."""
     b, h, n, d = v.shape
     nb = n // block_size
     vr = v.reshape(b * h, n, d)
+    # Each block's scalar fills one lane-dense (1, 128) output row: an
+    # out block whose trailing dims equal the array's satisfies Mosaic's
+    # 8x128 tiling rule, where a (1, 1) block of an (nb, 1) array does not.
     out = pl.pallas_call(
         functools.partial(_vmag_kernel, block_size=block_size),
         grid=(b * h, nb),
         in_specs=[pl.BlockSpec((1, block_size, d), lambda bh, i: (bh, i, 0))],
-        out_specs=pl.BlockSpec((1, 1, 1), lambda bh, i: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, nb, 1), jnp.float32),
-        compiler_params=pltpu_compat.CompilerParams(
+        out_specs=pl.BlockSpec((1, 1, 1, 128), lambda bh, i: (bh, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * h, nb, 1, 128), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="stem_value_magnitude",
     )(vr)
-    return out.reshape(b, h, nb)
+    return out[:, :, 0, 0].reshape(b, h, nb)

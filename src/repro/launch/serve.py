@@ -86,6 +86,17 @@ def _latency_stats(finished):
     return out
 
 
+def serving_policy(name: str, block_size: int):
+    """The named registered policy, rescaled to the serving geometry
+    (registered defaults carry paper geometry: B=128 over 8k+ contexts).
+    ignore_missing: content-free policies (streaming) have no
+    stride/min_budget fields to rewrite."""
+    from repro.core import policy as policy_lib
+    return policy_lib.get_policy(name).with_updates(
+        block_size=block_size, stride=4, sink_blocks=1, local_blocks=1,
+        min_budget_blocks=2, ignore_missing=True)
+
+
 def run_engine(args, cfg, bundle, params, stem_cfg, budget_frac):
     import jax.numpy as jnp  # noqa: F401  (keeps jax initialized up front)
     from repro.runtime.engine import EngineConfig, StemEngine
@@ -155,6 +166,7 @@ def run_engine(args, cfg, bundle, params, stem_cfg, budget_frac):
             "chaos": metrics["chaos"],
         },
         "tokens": {f.uid: f.tokens for f in finished},
+        "engine": engine,
         **stats,
     }
     print(f"engine ({out['prefill']}, {out['loop']}, {ecfg.scheduler}): "
@@ -357,11 +369,12 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    from repro import configs
+    from repro import backend, configs
     from repro.core.config import StemConfig
     from repro.models import registry
     import jax
 
+    backend.setup_compile_cache()
     cfg = configs.get_config(args.arch)
     if args.reduced:
         cfg = configs.reduced(cfg).replace(dtype="float32")
@@ -375,14 +388,7 @@ def main(argv=None) -> dict:
     bs = args.block_size or max(16, min(128, args.max_prompt // 8))
     bs = -(-bs // 8) * 8
     if args.policy:
-        # Resolve the named policy and rescale its geometry/stability knobs
-        # to the serving shape (registered defaults carry paper geometry:
-        # B=128 over 8k+ contexts).  ignore_missing: content-free policies
-        # (streaming) have no stride/min_budget fields to rewrite.
-        from repro.core import policy as policy_lib
-        stem_cfg = policy_lib.get_policy(args.policy).with_updates(
-            block_size=bs, stride=4, sink_blocks=1, local_blocks=1,
-            min_budget_blocks=2, ignore_missing=True)
+        stem_cfg = serving_policy(args.policy, bs)
         sparse = True
     else:
         stem_cfg = StemConfig(block_size=bs, min_budget_blocks=2, sink_blocks=1,

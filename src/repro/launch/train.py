@@ -66,7 +66,7 @@ def main(argv=None) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro import configs, optim
+    from repro import backend, configs, optim
     from repro.checkpoint import CheckpointManager
     from repro.core.config import StemConfig
     from repro.data import SyntheticLMData, make_global_batch
@@ -76,6 +76,7 @@ def main(argv=None) -> dict:
     from repro.runtime import FailureInjector, StragglerMonitor
     from repro.sharding import rules as rules_lib
 
+    backend.setup_compile_cache()
     cfg = configs.get_config(args.arch)
     if args.reduced:
         cfg = configs.reduced(cfg).replace(dtype="float32")

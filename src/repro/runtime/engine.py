@@ -866,6 +866,17 @@ class StemEngine:
         could not free enough pages — no pointless offloads."""
         if (self.ecfg.scheduler != "slo" or not self.ecfg.preemption):
             return False
+
+        def eligible():
+            return [s for s in self._group_slots(group)
+                    if self.slots[s] is not None
+                    and self.slots[s].req.priority < priority]
+
+        if not eligible():
+            # Nobody could be evicted: return without draining, or every
+            # step with a blocked equal-priority waiter would serialize
+            # the async pipeline.
+            return False
         if self._async and self._inflight:
             # Reconcile before evicting anyone: an in-flight step may
             # finish a request outright, freeing a slot and its pages —
@@ -875,9 +886,7 @@ class StemEngine:
             if (self._free_slot_in(group) is not None
                     and self.allocators[group].available >= need_pages):
                 return True
-        victims = [s for s in self._group_slots(group)
-                   if self.slots[s] is not None
-                   and self.slots[s].req.priority < priority]
+        victims = eligible()
         if not victims:
             return False
         # Only a victim's PRIVATE pages come back (shared prefix pages stay

@@ -6,7 +6,7 @@ import pytest
 
 from repro.core import StemConfig
 from repro.core.sparse_attention import select_for
-from repro.kernels import ops, ref
+from repro.kernels import block_sparse_attn, flash_attention, ref, stem_metric
 
 
 def _qkv(seed, b, hq, hk, n, d, dtype):
@@ -35,7 +35,7 @@ def _tol(dtype):
 )
 def test_flash_attention_sweep(b, hq, hk, n, d, bq, bk, dtype):
     q, k, v = _qkv(0, b, hq, hk, n, d, dtype)
-    got = ops.flash_attention(q, k, v, block_q=bq, block_k=bk)
+    got = flash_attention.flash_attention(q, k, v, block_q=bq, block_k=bk)
     want = ref.flash_attention_ref(q, k, v)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)
@@ -57,7 +57,8 @@ def test_block_sparse_attention_sweep(b, hq, hk, n, d, bs, frac, dtype):
     cfg = StemConfig(block_size=bs, k_start_frac=frac, mu=0.7, sink_blocks=1,
                      local_blocks=1, min_budget_blocks=1, stride=8)
     sel, _ = select_for(q, k, v, cfg)
-    got = ops.block_sparse_attention(q, k, v, sel.indices, sel.slot_mask, block_size=bs)
+    got = block_sparse_attn.block_sparse_attention(
+        q, k, v, sel.indices, sel.slot_mask, block_size=bs)
     want = ref.block_sparse_attention_ref(q, k, v, sel.indices, sel.slot_mask, block_size=bs)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)
@@ -70,8 +71,9 @@ def test_block_sparse_full_budget_equals_flash():
     cfg = StemConfig(block_size=64, k_start_frac=1.0, mu=1.0, sink_blocks=0,
                      local_blocks=1, min_budget_blocks=0, stride=8)
     sel, _ = select_for(q, k, v, cfg)
-    got = ops.block_sparse_attention(q, k, v, sel.indices, sel.slot_mask, block_size=64)
-    want = ops.flash_attention(q, k, v, block_q=64, block_k=64)
+    got = block_sparse_attn.block_sparse_attention(
+        q, k, v, sel.indices, sel.slot_mask, block_size=64)
+    want = flash_attention.flash_attention(q, k, v, block_q=64, block_k=64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6, rtol=3e-6)
 
 
@@ -79,7 +81,7 @@ def test_block_sparse_full_budget_equals_flash():
 @pytest.mark.parametrize("bs,stride,d", [(64, 8, 32), (128, 16, 64), (128, 16, 128)])
 def test_antidiag_pool_sweep(bs, stride, d, dtype):
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 3, 512, d), dtype)
-    got = ops.antidiag_pool(x, block_size=bs, stride=stride)
+    got = stem_metric.antidiag_pool(x, block_size=bs, stride=stride)
     want = ref.antidiag_pool_ref(x, bs, stride)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)
@@ -90,7 +92,7 @@ def test_antidiag_pool_sweep(bs, stride, d, dtype):
 @pytest.mark.parametrize("bs,d", [(64, 32), (128, 64), (128, 256)])
 def test_value_magnitude_sweep(bs, d, dtype):
     v = jax.random.normal(jax.random.PRNGKey(4), (1, 2, 512, d), dtype) * 3.0
-    got = ops.value_magnitude(v, block_size=bs)
+    got = stem_metric.value_magnitude(v, block_size=bs)
     want = ref.value_magnitude_ref(v, bs)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),

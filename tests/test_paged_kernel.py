@@ -6,7 +6,7 @@ Both executors are reached through the public entry points
 (``paged_sparse_decode`` / ``chunked_prefill_attention``) with the
 ``executor`` knob, exactly like the serving engine — so the differential
 also pins the ``core/policy.py`` paged-executor registry dispatch.  The
-Pallas side runs in interpret mode on CPU CI (kernels/paged_attn.INTERPRET);
+Pallas side runs in interpret mode on the CPU backend (repro/backend.py);
 the same tests compile to Mosaic on TPU.
 
 Covers the ISSUE matrix: GQA groups {1, 2, 4}, unaligned per-slot cache

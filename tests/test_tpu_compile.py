@@ -1,0 +1,185 @@
+"""Compile-only checks against the TPU compiler, without a chip.
+
+Interpret mode runs kernel bodies in Python and cannot see Mosaic's rules
+(8x128 block tiling, lane-aligned stores, VMEM limits).  These tests
+describe a v5e:2x2 topology and compile the serving main path for one of
+its chips, at qwen3-0.6b attention widths (bf16, head_dim 128, block 128,
+16 query / 8 KV heads), with kernels forced to ``interpret=False``.
+Nothing runs; a compile that passes here is not a chip run.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may hold the TPU library at a time, and every pytest
+worker imports this file.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro import backend, configs
+from repro.core import chunked as chunked_lib
+from repro.kernels import block_sparse_attn, flash_attention, paged_attn
+from repro.kernels import stem_metric
+from repro.launch import steps as steps_lib
+from repro.launch.serve import serving_policy
+from repro.models import registry, transformer
+from repro.runtime import sampling as sampling_lib
+from repro.runtime.engine import EngineConfig
+
+HQ, HK, D, BS, STRIDE = 16, 8, 128, 128, 4
+SLOTS, MAXP = 4, 17                 # 4 slots x (2048 prompt + 32 decode)
+PAGES = 1 + SLOTS * MAXP
+CHUNK = 2 * BS
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A chip compile written to the persistent cache cannot be read back
+    # without a chip; keep the cache out of it.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _has_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("lane", ["decode", "chunk"])
+def test_score_pages_compiles(spec, lane):
+    kg = spec((HK, PAGES, STRIDE, D), F32)
+    if lane == "decode":
+        fn = lambda q, kg, pt: paged_attn.decode_page_scores(
+            q, kg, pt, group=HQ // HK, interpret=False)
+        q, pt = spec((SLOTS, HQ, 1, D), BF16), spec((SLOTS, MAXP), I32)
+    else:
+        fn = lambda q, kg, pt: paged_attn.chunk_page_scores(
+            q, kg, pt, block_size=BS, pooling="antidiag", group=HQ // HK,
+            interpret=False)
+        q, pt = spec((1, HQ, CHUNK, D), BF16), spec((1, MAXP), I32)
+    _has_kernel(_compile(fn, q, kg, pt))
+
+
+@pytest.mark.parametrize("lane", ["decode", "chunk"])
+def test_attend_pages_compiles(spec, lane):
+    b, nc, rows, k_max = ((SLOTS, 1, 1, MAXP) if lane == "decode"
+                          else (1, CHUNK // BS, BS, 2))
+    pool = spec((HK, PAGES, BS, D), BF16)
+
+    def fn(q, kp, vp, gp, idx, cnt, pos):
+        return paged_attn._attend_pages(
+            q, kp, vp, gp, idx, cnt, pos, block_size=BS,
+            causal=lane == "chunk", interpret=False, name=f"attend_{lane}")
+    _has_kernel(_compile(
+        fn, spec((b, HQ, nc, rows, D), BF16), pool, pool,
+        spec((b, HQ, nc, k_max), I32), spec((b, HQ, nc, k_max), I32),
+        spec((b, HQ, nc), I32), spec((b,), I32)))
+
+
+@pytest.mark.parametrize("group_dedup", [False, True])
+def test_block_sparse_attention_compiles(spec, group_dedup):
+    n, k_max = 8192, 16
+    h_sel = HK if group_dedup else HQ
+    fn = lambda q, k, v, idx, mask: block_sparse_attn.block_sparse_attention(
+        q, k, v, idx, mask, block_size=BS, group_dedup=group_dedup,
+        interpret=False)
+    _has_kernel(_compile(
+        fn, spec((1, HQ, n, D), BF16), spec((1, HK, n, D), BF16),
+        spec((1, HK, n, D), BF16), spec((1, h_sel, n // BS, k_max), I32),
+        spec((1, h_sel, n // BS, k_max), jnp.bool_)))
+
+
+@pytest.mark.parametrize("kernel", ["value_magnitude", "antidiag_pool",
+                                    "flash_attention"])
+def test_dense_kernels_compile(spec, kernel):
+    x = spec((1, HK, 2048, D), BF16)
+    if kernel == "value_magnitude":
+        fn = lambda v: stem_metric.value_magnitude(v, block_size=BS,
+                                                   interpret=False)
+        args = (x,)
+    elif kernel == "antidiag_pool":
+        fn = lambda v: stem_metric.antidiag_pool(v, block_size=BS,
+                                                 stride=STRIDE,
+                                                 interpret=False)
+        args = (x,)
+    else:
+        fn = lambda q, k, v: flash_attention.flash_attention(
+            q, k, v, interpret=False)
+        args = (spec((1, HQ, 2048, D), BF16), x, x)
+    _has_kernel(_compile(fn, *args))
+
+
+@pytest.mark.parametrize("executor,loop", [("xla", "sync"),
+                                           ("pallas", "sync"),
+                                           ("pallas", "async")])
+def test_unified_step_compiles(spec, monkeypatch, executor, loop):
+    """The engine's unified step, both signatures (mixed and decode-only),
+    at qwen3-0.6b width with the depth cut to 2 layers, for the serving
+    geometry chip_smoke.py runs (4 slots, 2048 + 32 tokens, 256-token
+    chunks).  This process's backend is the CPU, so the pallas case steers
+    the kernels to Mosaic here."""
+    if executor == "pallas":
+        monkeypatch.setattr(backend, "interpret_kernels", lambda: False)
+    cfg = configs.get_config("qwen3-0.6b").replace(num_layers=2)
+    bundle = registry.build(cfg)
+    pol = serving_policy("stem", BS)
+    ecfg = EngineConfig.for_trace(max_slots=SLOTS, max_prompt=2048,
+                                  max_new_tokens=32, page_size=BS)
+    P = ecfg.max_pages_per_slot
+    assert (P, ecfg.num_pages) == (MAXP, PAGES)
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
+    params = shaped(jax.eval_shape(bundle.init_params,
+                                   jax.random.PRNGKey(0)))
+    pools = shaped(jax.eval_shape(
+        lambda: transformer.init_page_pools(cfg, PAGES, pol)))
+    chunk = {"tokens": spec((1, CHUNK), I32), "page_table": spec((1, P), I32),
+             "start": spec((1,), I32), "true_len": spec((1,), I32),
+             "budgets": spec((1, CHUNK // BS), I32), "last": spec((1,), I32)}
+    sampler = None
+    if loop == "async":
+        sampler = sampling_lib.get_sampler("greedy")
+        chunk.update(slot=spec((1,), I32), emit=spec((1,), jnp.bool_))
+        lead = (spec((SLOTS,), I32), spec((SLOTS,), jnp.bool_))
+    else:
+        lead = (spec((SLOTS, 1), I32),)
+    step = jax.jit(steps_lib.make_unified_step(
+        bundle, stem_cfg=pol, budget_frac=0.5,
+        chunk_k_max=chunked_lib.chunk_budget_bound(pol, P),
+        executor=executor, sampler=sampler))
+    for ch in (chunk, None):
+        compiled = step.lower(params, pools, *lead, spec((SLOTS, P), I32),
+                              spec((SLOTS,), I32), ch).compile()
+        if executor == "pallas":
+            _has_kernel(compiled)
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
