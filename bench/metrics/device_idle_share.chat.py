@@ -1,0 +1,2 @@
+"""device_idle_share of the chat cells; see readers.device_idle_share."""
+from readers import device_idle_share as read  # noqa: F401

@@ -1,0 +1,2 @@
+"""mfu of the decode cells; see readers.mfu."""
+from readers import mfu as read  # noqa: F401
