@@ -1,0 +1,2 @@
+"""attend_ms_per_step of the decode cells; see phases.attend_ms_per_step."""
+from phases import attend_ms_per_step as read  # noqa: F401

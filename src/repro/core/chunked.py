@@ -239,26 +239,28 @@ def _chunked_prefill_xla(
     nc = c // bs
     maxp = page_table.shape[1]
 
-    # Page summaries through the page table (cheap: pooled reps only).
-    kg_rows = jnp.swapaxes(pool.kg[:, page_table], 0, 1)  # (b, hk, P, s, d)
-    vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)  # (b, hk, P)
+    with jax.named_scope("stem.score"):
+        # Page summaries through the page table (cheap: pooled reps only).
+        kg_rows = jnp.swapaxes(pool.kg[:, page_table], 0, 1)  # (b,hk,P,s,d)
+        vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)  # (b, hk, P)
+        m = policy.chunk_scores(q, kg_rows, vm_rows)      # (b, hq, nc, P)
 
-    m = policy.chunk_scores(q, kg_rows, vm_rows)          # (b, hq, nc, P)
-    rows = chunk_start[:, None] // bs + jnp.arange(nc)[None, :]
-    sel = select_chunk_blocks(m, rows, budgets, policy, k_max)
-    kk = sel.indices.shape[-1]
-
-    # Logical slot -> global page id, then fetch only the selected pages.
-    idx = sel.indices.reshape(b, hk, group, nc, kk)
-    gp = jnp.take_along_axis(
-        jnp.broadcast_to(page_table[:, None, None, None, :],
-                         (b, hk, group, nc, maxp)),
-        idx, axis=-1)                                      # (b,hk,g,nc,kmax)
+    with jax.named_scope("stem.select"):
+        rows = chunk_start[:, None] // bs + jnp.arange(nc)[None, :]
+        sel = select_chunk_blocks(m, rows, budgets, policy, k_max)
+        kk = sel.indices.shape[-1]
+        # Logical slot -> global page id of each selected page.
+        idx = sel.indices.reshape(b, hk, group, nc, kk)
+        gp = jnp.take_along_axis(
+            jnp.broadcast_to(page_table[:, None, None, None, :],
+                             (b, hk, group, nc, maxp)),
+            idx, axis=-1)                                  # (b,hk,g,nc,kmax)
 
     def fetch(kp, vp, gph):
         # kp, vp: (P, page, d); gph: (b, g, nc, kmax).
         return kp[gph], vp[gph]
 
-    gk, gv = jax.vmap(fetch, in_axes=(0, 0, 1), out_axes=1)(
-        pool.k, pool.v, gp)                        # (b, hk, g, nc, kmax, bs, d)
-    return attend_chunk(q, gk, gv, sel, chunk_start, bs)
+    with jax.named_scope("stem.attend"):
+        gk, gv = jax.vmap(fetch, in_axes=(0, 0, 1), out_axes=1)(
+            pool.k, pool.v, gp)                    # (b, hk, g, nc, kmax, bs, d)
+        return attend_chunk(q, gk, gv, sel, chunk_start, bs)

@@ -410,27 +410,30 @@ def fused_paged_decode(q, pool, page_table, cache_lens, cfg,
     maxp = page_table.shape[1]
     lens = jnp.broadcast_to(jnp.asarray(cache_lens, jnp.int32), (b,))
 
-    if kind == "zero":
-        m = jnp.zeros((b, hk, group, maxp), jnp.float32)
-    else:
-        m = decode_page_scores(q, pool.kg, page_table, group=group,
-                               interpret=interpret)
-        beta = getattr(policy.metric, "beta", 0.0)
-        if beta:
-            vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)
-            m = m + beta * jnp.maximum(vm_rows, 0.0)[:, :, None, :]
+    with jax.named_scope("stem.score"):
+        if kind == "zero":
+            m = jnp.zeros((b, hk, group, maxp), jnp.float32)
+        else:
+            m = decode_page_scores(q, pool.kg, page_table, group=group,
+                                   interpret=interpret)
+            beta = getattr(policy.metric, "beta", 0.0)
+            if beta:
+                vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)
+                m = m + beta * jnp.maximum(vm_rows, 0.0)[:, :, None, :]
 
-    sel = policy.decode_select(m, lens, budget_frac=budget_frac)
-    debug_assert_live_rows(sel, context="fused_paged_decode")
-    gp, idx, cnt = pack_selection(sel.indices, sel.live, page_table)
-    out = _attend_pages(
-        q.reshape(b, hq, 1, 1, d),
-        pool.k, pool.v,
-        gp.reshape(b, hq, 1, -1), idx.reshape(b, hq, 1, -1),
-        cnt.reshape(b, hq, 1), lens,
-        block_size=policy.block_size, causal=False, interpret=interpret,
-        name="stem_paged_decode_attend")
-    return out.reshape(b, hq, 1, -1)
+    with jax.named_scope("stem.select"):
+        sel = policy.decode_select(m, lens, budget_frac=budget_frac)
+        debug_assert_live_rows(sel, context="fused_paged_decode")
+        gp, idx, cnt = pack_selection(sel.indices, sel.live, page_table)
+    with jax.named_scope("stem.attend"):
+        out = _attend_pages(
+            q.reshape(b, hq, 1, 1, d),
+            pool.k, pool.v,
+            gp.reshape(b, hq, 1, -1), idx.reshape(b, hq, 1, -1),
+            cnt.reshape(b, hq, 1), lens,
+            block_size=policy.block_size, causal=False, interpret=interpret,
+            name="stem_paged_decode_attend")
+        return out.reshape(b, hq, 1, -1)
 
 
 def fused_paged_chunk(q, pool, page_table, chunk_start, budgets, cfg,
@@ -464,29 +467,32 @@ def fused_paged_chunk(q, pool, page_table, chunk_start, budgets, cfg,
     maxp = page_table.shape[1]
     start = jnp.asarray(chunk_start, jnp.int32)
 
-    if kind == "zero":
-        m = jnp.zeros((b, hq, nc, maxp), jnp.float32)
-    else:
-        m = chunk_page_scores(q, pool.kg, page_table, block_size=bs,
-                              pooling=pooling, group=group,
-                              interpret=interpret)
-        beta = getattr(policy.metric, "beta", 0.0)
-        if beta:
-            vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)
-            mv = jnp.repeat(vm_rows, group, axis=1)        # (b, hq, maxp)
-            m = m + beta * jnp.maximum(mv, 0.0)[..., None, :]
-        m = metric_lib.group_reduce_metric(m, group, policy.group_reduce)
+    with jax.named_scope("stem.score"):
+        if kind == "zero":
+            m = jnp.zeros((b, hq, nc, maxp), jnp.float32)
+        else:
+            m = chunk_page_scores(q, pool.kg, page_table, block_size=bs,
+                                  pooling=pooling, group=group,
+                                  interpret=interpret)
+            beta = getattr(policy.metric, "beta", 0.0)
+            if beta:
+                vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)
+                mv = jnp.repeat(vm_rows, group, axis=1)    # (b, hq, maxp)
+                m = m + beta * jnp.maximum(mv, 0.0)[..., None, :]
+            m = metric_lib.group_reduce_metric(m, group, policy.group_reduce)
 
-    rows = start[:, None] // bs + jnp.arange(nc)[None, :]
-    sel = chunked_lib.select_chunk_blocks(m, rows, budgets, policy, k_max)
-    gp, idx, cnt = pack_selection(sel.indices, sel.live, page_table)
-    out = _attend_pages(
-        q.reshape(b, hq, nc, bs, d),
-        pool.k, pool.v,
-        gp, idx, cnt, start,
-        block_size=bs, causal=True, interpret=interpret,
-        name="stem_paged_chunk_attend")
-    return out.reshape(b, hq, c, -1)
+    with jax.named_scope("stem.select"):
+        rows = start[:, None] // bs + jnp.arange(nc)[None, :]
+        sel = chunked_lib.select_chunk_blocks(m, rows, budgets, policy, k_max)
+        gp, idx, cnt = pack_selection(sel.indices, sel.live, page_table)
+    with jax.named_scope("stem.attend"):
+        out = _attend_pages(
+            q.reshape(b, hq, nc, bs, d),
+            pool.k, pool.v,
+            gp, idx, cnt, start,
+            block_size=bs, causal=True, interpret=interpret,
+            name="stem_paged_chunk_attend")
+        return out.reshape(b, hq, c, -1)
 
 
 # Both fused lanes read head counts from the pool shapes and reduce only

@@ -263,22 +263,27 @@ def apply_decode_paged(
 
     stem_cfg = policy_lib.as_policy(stem_cfg)
     lens = jnp.asarray(cache_lens, jnp.int32)
-    q, k_new, v_new = _project(params, x, cfg, lens[:, None], use_rope=use_rope)
-    # Under the tensor-parallel head-sharding context the full projections
-    # above are computed replicated; each shard keeps its contiguous block
-    # of (query and KV) heads, appends/attends shard-local against its pool
-    # slice, and the per-head outputs are all-gathered back into full head
-    # order before the (replicated) output projection — bitwise identical
-    # to the single-device step.  All three calls are no-ops outside a mesh.
-    q = serving_lib.local_heads(q, axis=1)
-    k_new = serving_lib.local_heads(k_new, axis=1)
-    v_new = serving_lib.local_heads(v_new, axis=1)
+    with jax.named_scope("stem.qkv"):
+        q, k_new, v_new = _project(params, x, cfg, lens[:, None],
+                                   use_rope=use_rope)
+        # Under the tensor-parallel head-sharding context the full
+        # projections above are computed replicated; each shard keeps its
+        # contiguous block of (query and KV) heads, appends/attends
+        # shard-local against its pool slice, and the per-head outputs are
+        # all-gathered back into full head order before the (replicated)
+        # output projection — bitwise identical to the single-device step.
+        # All three calls are no-ops outside a mesh.
+        q = serving_lib.local_heads(q, axis=1)
+        k_new = serving_lib.local_heads(k_new, axis=1)
+        v_new = serving_lib.local_heads(v_new, axis=1)
     pool = paged_lib.append_token(pool, page_table, lens, k_new, v_new, stem_cfg)
     o = paged_lib.paged_sparse_decode(q, pool, page_table, lens + 1, stem_cfg,
                                       budget_frac=budget_frac,
                                       executor=executor)
-    o = serving_lib.gather_heads(o, axis=1)
-    out = jnp.einsum("bhsk,hkd->bsd", o.astype(x.dtype), params["wo"])
+    with jax.named_scope("stem.attend"):
+        o = serving_lib.gather_heads(o, axis=1)
+    with jax.named_scope("stem.o_proj"):
+        out = jnp.einsum("bhsk,hkd->bsd", o.astype(x.dtype), params["wo"])
     return out, pool
 
 
@@ -312,20 +317,25 @@ def apply_chunk_paged(
 
     stem_cfg = policy_lib.as_policy(stem_cfg)
     c = x.shape[1]
-    positions = chunk_start[:, None] + jnp.arange(c)[None, :]     # (slots, C)
-    q, k_new, v_new = _project(params, x, cfg, positions, use_rope=use_rope)
-    # Same TP head slicing as apply_decode_paged: replicated projections,
-    # shard-local chunk write + selection + attention, all-gather before wo.
-    q = serving_lib.local_heads(q, axis=1)
-    k_new = serving_lib.local_heads(k_new, axis=1)
-    v_new = serving_lib.local_heads(v_new, axis=1)
+    with jax.named_scope("stem.qkv"):
+        positions = chunk_start[:, None] + jnp.arange(c)[None, :]  # (slots, C)
+        q, k_new, v_new = _project(params, x, cfg, positions,
+                                   use_rope=use_rope)
+        # Same TP head slicing as apply_decode_paged: replicated
+        # projections, shard-local chunk write + selection + attention,
+        # all-gather before wo.
+        q = serving_lib.local_heads(q, axis=1)
+        k_new = serving_lib.local_heads(k_new, axis=1)
+        v_new = serving_lib.local_heads(v_new, axis=1)
     pool = paged_lib.write_chunk_pages(pool, page_table, chunk_start, k_new,
                                        v_new, true_len, stem_cfg)
     o = chunked_lib.chunked_prefill_attention(q, pool, page_table,
                                               chunk_start, budgets, stem_cfg,
                                               k_max, executor=executor)
-    o = serving_lib.gather_heads(o, axis=1)
-    out = jnp.einsum("bhsk,hkd->bsd", o.astype(x.dtype), params["wo"])
+    with jax.named_scope("stem.attend"):
+        o = serving_lib.gather_heads(o, axis=1)
+    with jax.named_scope("stem.o_proj"):
+        out = jnp.einsum("bhsk,hkd->bsd", o.astype(x.dtype), params["wo"])
     return out, pool
 
 

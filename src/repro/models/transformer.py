@@ -702,14 +702,24 @@ def paged_mixed_step(params, tokens: jnp.ndarray, pools,
 
     Returns (decode logits (slots, vocab),
              chunk logits (L, vocab) | None, new pools).
+
+    Device phases carry ``jax.named_scope`` names, so each op of the
+    compiled step is owned by one phase in a profiler trace: the lanes
+    ``stem.decode_lane`` / ``stem.chunk_lane`` enclose ``stem.qkv``,
+    ``stem.kv_write``, ``stem.score``, ``stem.select``, ``stem.attend``,
+    ``stem.o_proj`` and ``stem.mlp``; ``stem.embed`` and ``stem.head`` sit
+    outside the layers.  What the layer scan itself adds (slicing each
+    layer's pools and stacking the new ones) carries no phase.
     """
-    x = common.embed_lookup(params["embed"], tokens, cfg.jnp_dtype)
-    xc = None
-    if chunk is not None:
-        xc = common.embed_lookup(params["embed"], chunk["tokens"], cfg.jnp_dtype)
-    if cfg.embed_scale_flag:
-        x = x * (cfg.d_model ** 0.5)
-        xc = None if xc is None else xc * (cfg.d_model ** 0.5)
+    with jax.named_scope("stem.embed"):
+        x = common.embed_lookup(params["embed"], tokens, cfg.jnp_dtype)
+        xc = None
+        if chunk is not None:
+            xc = common.embed_lookup(params["embed"], chunk["tokens"],
+                                     cfg.jnp_dtype)
+        if cfg.embed_scale_flag:
+            x = x * (cfg.d_model ** 0.5)
+            xc = None if xc is None else xc * (cfg.d_model ** 0.5)
     new_pools = []
     for si, (n, kinds) in enumerate(layer_program(cfg)):
         seg = params[f"segment{si}"]
@@ -722,30 +732,41 @@ def paged_mixed_step(params, tokens: jnp.ndarray, pools,
             for i, k in enumerate(kinds):
                 p = layer_params[f"sub{i}"]
                 pl = pool[f"sub{i}"]
+
+                def ffn(h, k=k, p=p):
+                    with jax.named_scope("stem.mlp"):
+                        h2 = common.rms_norm(h, p["norm2"])
+                        if k == "moe":
+                            y, _ = moe.apply(p["ffn"], h2, cfg.moe,
+                                             cfg.activation)
+                            return h + y
+                        return h + mlp.apply(p["ffn"], h2, cfg.activation)
+
                 if chunk is not None:
-                    hc = common.rms_norm(xc, p["norm1"])
-                    mix_c, pl = attention.apply_chunk_paged(
-                        p["attn"], hc, cfg, pl, chunk["page_table"],
-                        chunk["start"], chunk["true_len"], chunk["budgets"],
-                        stem_cfg, k_max=chunk_k_max, executor=executor)
-                    xc = xc + mix_c
-                h = common.rms_norm(x, p["norm1"])
-                mix, pl = attention.apply_decode_paged(
-                    p["attn"], h, cfg, pl, page_table,
-                    cache_lens, stem_cfg, budget_frac=budget_frac,
-                    executor=executor)
-                x = x + mix
+                    with jax.named_scope("stem.chunk_lane"):
+                        with jax.named_scope("stem.qkv"):
+                            hc = common.rms_norm(xc, p["norm1"])
+                        mix_c, pl = attention.apply_chunk_paged(
+                            p["attn"], hc, cfg, pl, chunk["page_table"],
+                            chunk["start"], chunk["true_len"],
+                            chunk["budgets"], stem_cfg, k_max=chunk_k_max,
+                            executor=executor)
+                        with jax.named_scope("stem.o_proj"):
+                            xc = xc + mix_c
+                with jax.named_scope("stem.decode_lane"):
+                    with jax.named_scope("stem.qkv"):
+                        h = common.rms_norm(x, p["norm1"])
+                    mix, pl = attention.apply_decode_paged(
+                        p["attn"], h, cfg, pl, page_table,
+                        cache_lens, stem_cfg, budget_frac=budget_frac,
+                        executor=executor)
+                    with jax.named_scope("stem.o_proj"):
+                        x = x + mix
+                    x = ffn(x)
                 new_pool[f"sub{i}"] = pl
-
-                def ffn(h2, k=k, p=p):
-                    if k == "moe":
-                        y, _ = moe.apply(p["ffn"], h2, cfg.moe, cfg.activation)
-                        return y
-                    return mlp.apply(p["ffn"], h2, cfg.activation)
-
-                x = x + ffn(common.rms_norm(x, p["norm2"]))
                 if chunk is not None:
-                    xc = xc + ffn(common.rms_norm(xc, p["norm2"]))
+                    with jax.named_scope("stem.chunk_lane"):
+                        xc = ffn(xc)
             return (x, xc), new_pool
 
         if n == 1:
@@ -756,11 +777,12 @@ def paged_mixed_step(params, tokens: jnp.ndarray, pools,
         else:
             (x, xc), npool = jax.lax.scan(body, (x, xc), (seg, pool))
         new_pools.append(npool)
-    dec_logits = _logits(params, x, cfg)[:, 0]
-    chunk_logits = None
-    if chunk is not None:
-        xl = jnp.take_along_axis(xc, chunk["last"][:, None, None], axis=1)
-        chunk_logits = _logits(params, xl, cfg)[:, 0]
+    with jax.named_scope("stem.head"):
+        dec_logits = _logits(params, x, cfg)[:, 0]
+        chunk_logits = None
+        if chunk is not None:
+            xl = jnp.take_along_axis(xc, chunk["last"][:, None, None], axis=1)
+            chunk_logits = _logits(params, xl, cfg)[:, 0]
     return dec_logits, chunk_logits, new_pools
 
 
@@ -793,16 +815,18 @@ def paged_sampled_step(params, token_buf: jnp.ndarray, pools,
         params, token_buf[:, None], pools, page_table, cache_lens, cfg,
         stem_cfg=stem_cfg, budget_frac=budget_frac, chunk=chunk,
         chunk_k_max=chunk_k_max, executor=executor)
-    dec_ids = sampler(dec_logits)
-    new_buf = jnp.where(dec_mask, dec_ids, token_buf)
-    chunk_ids = None
-    if chunk is not None:
-        chunk_ids = sampler(chunk_logits)
-        # Completed-prefill lanes feed their first token into the buffer;
-        # idle / mid-prompt lanes scatter out of bounds and are dropped.
-        slots = token_buf.shape[0]
-        target = jnp.where(chunk["emit"], chunk["slot"], slots)
-        new_buf = new_buf.at[target].set(chunk_ids, mode="drop")
+    with jax.named_scope("stem.sample"):
+        dec_ids = sampler(dec_logits)
+        new_buf = jnp.where(dec_mask, dec_ids, token_buf)
+        chunk_ids = None
+        if chunk is not None:
+            chunk_ids = sampler(chunk_logits)
+            # Completed-prefill lanes feed their first token into the
+            # buffer; idle / mid-prompt lanes scatter out of bounds and are
+            # dropped.
+            slots = token_buf.shape[0]
+            target = jnp.where(chunk["emit"], chunk["slot"], slots)
+            new_buf = new_buf.at[target].set(chunk_ids, mode="drop")
     return dec_ids, chunk_ids, new_buf, new_pools
 
 

@@ -85,6 +85,18 @@ still-reserved pages, and the emitted streams stay bit-identical to the
 The only per-step transfer is the ``(slots,) int32`` id array, and
 ``stats["host_syncs"]`` (blocking fetches with no newer step queued
 behind them) drops from O(steps) to O(finished requests).
+
+Host spans: every ``step()`` writes ``jax.profiler.TraceAnnotation`` spans
+onto the profiler's clock, the same clock as the device trace, so an idle
+gap on the device can be charged to what the host was doing:
+``engine.step`` (the whole iteration) encloses ``engine.admit``,
+``engine.schedule``, ``engine.inputs`` (host arrays and their transfer),
+``engine.dispatch`` (the jitted call), ``engine.wait`` (the blocking fetch)
+and ``engine.emit`` (argmax, token append, recycle, prefix registration);
+the async loop wraps each reconcile's wait and emit in
+``engine.reconcile``.  ``stats["dispatch_s"]`` and ``stats["sync_wait_s"]``
+are the host-clock lengths of the dispatch and wait spans.  With the
+profiler off a span costs about a microsecond.
 """
 from __future__ import annotations
 
@@ -97,6 +109,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from repro.core import chunked as chunked_lib
 from repro.launch import steps as steps_lib
@@ -491,11 +504,13 @@ class StemEngine:
                       "prefix_cows": 0, "admission_rejects": 0,
                       "host_syncs": 0, "id_fetches": 0,
                       "lookahead_discards": 0, "pallas_fallbacks": 0,
-                      "restore_bytes": 0,
-                      "dispatch_s": 0.0, "sync_wait_s": 0.0}
+                      "restore_bytes": 0, "admissions": 0,
+                      "dispatch_s": 0.0, "sync_wait_s": 0.0,
+                      "queue_wait_s": 0.0}
         self._slot_ever_used = [False] * T
         self._seq: dict = {}                   # uid -> submission order
-        self._arrival_t: dict = {}             # uid -> first-schedulable wall
+        self._arrival_t: dict = {}             # uid -> when it could first
+                                               # be scheduled (perf_counter)
         self._next_seq = 0
         self._last_chunk_step = [0] * self.groups
                                                # last step a chunk ran (or no
@@ -642,6 +657,8 @@ class StemEngine:
             raise ValueError(f"duplicate request uid {req.uid}")
         self._seq[req.uid] = self._next_seq
         self._next_seq += 1
+        if req.arrival_step <= self.step_count:
+            self._arrival_t[req.uid] = time.perf_counter()
         self.waiting.append(req)
 
     def _pages_needed(self, prompt_len: int, max_new: int) -> int:
@@ -658,8 +675,8 @@ class StemEngine:
         self.finished.clear()
         keep = ("traces", "prefill_traces", "pallas_fallbacks")
         self.stats.update({k: 0 for k in self.stats if k not in keep})
-        self.stats["dispatch_s"] = 0.0
-        self.stats["sync_wait_s"] = 0.0
+        for k in ("dispatch_s", "sync_wait_s", "queue_wait_s"):
+            self.stats[k] = 0.0
         self._slot_ever_used = [False] * self.total_slots
         self.monitor.flagged.clear()
 
@@ -674,25 +691,15 @@ class StemEngine:
 
     @property
     def metrics(self) -> dict:
-        """Live observability: straggler flags, offload residency, chaos
-        counters — the serving-side mirror of ``stats`` for dashboards."""
-        self._refresh_fallbacks()
+        """Live observability beside ``stats``: in-flight steps, the
+        restore-cost model's bandwidth estimate, straggler flags, offload
+        peak and chaos counters."""
         return {
             "inflight_steps": len(self._inflight),
             "h2d_bw_bytes_per_s": self._h2d_bw_ema,
-            "pallas_fallbacks": self.stats["pallas_fallbacks"],
             "step_time_ema_s": self.monitor.ema,
             "straggler_steps": list(self.monitor.flagged),
-            "offloaded_requests": len(self.preempted),
-            "offload_resident_bytes": self.host_store.nbytes,
             "offload_peak_bytes": self.host_store.peak_nbytes,
-            "allocator_evictions": sum(a.evictions for a in self.allocators),
-            "allocator_restores": sum(a.restores for a in self.allocators),
-            "allocator_total_alloced": sum(a.total_alloced
-                                           for a in self.allocators),
-            "prefix_shares": sum(a.shares for a in self.allocators),
-            "prefix_cached_pages": sum(a.cached_pages
-                                       for a in self.allocators),
             "chaos": self.chaos.counts if self.chaos else None,
         }
 
@@ -1209,7 +1216,9 @@ class StemEngine:
         self.slot_pages[slot] = all_pages
         self.slot_nshared[slot] = n_share
         now = time.perf_counter()
-        arrival = self._arrival_t.get(req.uid, now)
+        arrival = self._arrival_t.pop(req.uid, now)
+        self.stats["admissions"] += 1
+        self.stats["queue_wait_s"] += now - arrival
 
         if self.ecfg.monolithic_prefill:
             # Legacy: prefill the whole prompt at admission (resets the
@@ -1449,8 +1458,45 @@ class StemEngine:
         # retry of this step never double-applies summary increments.
         if self.chaos:
             self.chaos.maybe_fail_step(self.step_count)
-        dec, grants = self._schedule(dec_all, pre_all, time.perf_counter())
+        with _span("engine.schedule"):
+            dec, grants = self._schedule(dec_all, pre_all,
+                                         time.perf_counter())
+        with _span("engine.inputs"):
+            dec_in, tab_in, len_in, chunk = self._sync_inputs(dec, grants)
+        any_grant = any(grants)
+        t_dispatch = time.perf_counter()
+        with _span("engine.dispatch"):
+            dec_logits, chunk_logits, self.pools = self._unified(
+                self.params, self.pools, dec_in, tab_in, len_in, chunk)
+        t_fetch = time.perf_counter()
+        self.stats["dispatch_s"] += t_fetch - t_dispatch
+        # The ONLY per-step host syncs, mesh or not: one logits fetch per
+        # active lane kind (tracked so the scaling benchmark can assert the
+        # mesh adds none).
+        with _span("engine.wait"):
+            if dec:
+                dec_logits = np.asarray(dec_logits)
+                if self.smesh is not None:
+                    dec_logits = dec_logits.reshape(self.total_slots, -1)
+                self.stats["host_syncs"] += 1
+            if any_grant:
+                chunk_logits = np.asarray(chunk_logits)
+                if self.smesh is None:
+                    chunk_logits = chunk_logits[None]   # (1, L, vocab)
+                self.stats["host_syncs"] += 1
+        now = time.perf_counter()
+        self.stats["sync_wait_s"] += now - t_fetch
+        self.stats["step_calls"] += 1
+        if dec:
+            self.stats["decode_steps"] += 1
+        with _span("engine.emit"):
+            self._emit_sync(dec, grants, dec_logits, chunk_logits, now)
+        return True
 
+    def _sync_inputs(self, dec: list, grants: list):
+        """The synchronous step's device inputs: decode tokens, page-table
+        rows and lengths for the granted decode slots, and the chunk lane
+        (None when no chunk was granted)."""
         G, Sg = self.groups, self.slots_per_group
         C = self.chunk_size
         T, P = self.total_slots, self.ecfg.max_pages_per_slot
@@ -1505,30 +1551,14 @@ class StemEngine:
             dec_in = jnp.asarray(tokens)
             tab_in = jnp.asarray(dec_table)
             len_in = jnp.asarray(dec_lens)
-        t_dispatch = time.perf_counter()
-        dec_logits, chunk_logits, self.pools = self._unified(
-            self.params, self.pools, dec_in, tab_in, len_in, chunk)
-        t_fetch = time.perf_counter()
-        self.stats["dispatch_s"] += t_fetch - t_dispatch
-        # The ONLY per-step host syncs, mesh or not: one logits fetch per
-        # active lane kind (tracked so the scaling benchmark can assert the
-        # mesh adds none).
-        if dec:
-            dec_logits = np.asarray(dec_logits)
-            if self.smesh is not None:
-                dec_logits = dec_logits.reshape(T, -1)
-            self.stats["host_syncs"] += 1
-        if any_grant:
-            chunk_logits = np.asarray(chunk_logits)
-            if self.smesh is None:
-                chunk_logits = chunk_logits[None]       # (1, L, vocab)
-            self.stats["host_syncs"] += 1
-        now = time.perf_counter()
-        self.stats["sync_wait_s"] += now - t_fetch
-        self.stats["step_calls"] += 1
-        if dec:
-            self.stats["decode_steps"] += 1
+        return dec_in, tab_in, len_in, chunk
 
+    def _emit_sync(self, dec: list, grants: list, dec_logits, chunk_logits,
+                   now: float) -> None:
+        """Absorb one synchronous step's logits: argmax each granted lane,
+        append and time-stamp the tokens, finish prefills (registering
+        their prefix pages) and recycle finished slots."""
+        C = self.chunk_size
         for s in dec:
             self.cache_lens[s] += 1       # the fed-back token is now cached
             st = self.slots[s]
@@ -1566,7 +1596,6 @@ class StemEngine:
                     self.stats["tokens_generated"] += 1
                     if self._is_finished(st):
                         self._recycle(s)
-        return True
 
     # -- async pipeline -----------------------------------------------------
 
@@ -1582,6 +1611,44 @@ class StemEngine:
         ``_reconcile``.  Decode inputs come from the device-resident
         ``token_buf``; idle lanes are masked out and their trash-page
         writes discarded, exactly like the sync step."""
+        with _span("engine.inputs"):
+            mask_in, tab_in, len_in, chunk, dec_entries, chunk_entries = (
+                self._async_inputs(dec, grants))
+        t0 = time.perf_counter()
+        with _span("engine.dispatch"):
+            dec_ids, chunk_ids, self.token_buf, self.pools = self._unified(
+                self.params, self.pools, self.token_buf, mask_in, tab_in,
+                len_in, chunk)
+        t1 = time.perf_counter()
+        self.stats["dispatch_s"] += t1 - t0
+        self.stats["step_calls"] += 1
+        if dec:
+            self.stats["decode_steps"] += 1
+
+        for s in dec:
+            self.cache_lens[s] += 1   # the fed-back token is now cached
+            self.slots[s].inflight += 1
+        for g, lane, s, st, completes in chunk_entries:
+            st.prefill_pos += self.chunk_size
+            self.stats["chunks"] += 1
+            if completes:
+                st.phase = "decode"
+                self.cache_lens[s] = st.true_len
+                st.inflight += 1      # the first token is in flight
+                if st.prefix_keys:
+                    for j, key in enumerate(st.prefix_keys):
+                        self.allocators[g].register(
+                            self.slot_pages[s][j], key)
+                self.stats["prefills"] += 1
+        self._inflight.append(_InFlight(
+            dec_ids=dec_ids, chunk_ids=chunk_ids, dec=dec_entries,
+            chunks=chunk_entries, step=self.step_count, dispatch_t=t1))
+
+    def _async_inputs(self, dec: list, grants: list):
+        """The async step's device inputs (decode mask, page-table rows and
+        lengths, the chunk lane or None) and the host entries its reconcile
+        will absorb: ``(slot, state)`` per decode and ``(group, lane, slot,
+        state, completes)`` per chunk."""
         G, Sg, C = self.groups, self.slots_per_group, self.chunk_size
         T, P = self.total_slots, self.ecfg.max_pages_per_slot
         mask = np.zeros((T,), bool)
@@ -1649,34 +1716,7 @@ class StemEngine:
             mask_in = jnp.asarray(mask)
             tab_in = jnp.asarray(dec_table)
             len_in = jnp.asarray(dec_lens)
-        t0 = time.perf_counter()
-        dec_ids, chunk_ids, self.token_buf, self.pools = self._unified(
-            self.params, self.pools, self.token_buf, mask_in, tab_in,
-            len_in, chunk)
-        t1 = time.perf_counter()
-        self.stats["dispatch_s"] += t1 - t0
-        self.stats["step_calls"] += 1
-        if dec:
-            self.stats["decode_steps"] += 1
-
-        for s in dec:
-            self.cache_lens[s] += 1   # the fed-back token is now cached
-            self.slots[s].inflight += 1
-        for g, lane, s, st, completes in chunk_entries:
-            st.prefill_pos += C
-            self.stats["chunks"] += 1
-            if completes:
-                st.phase = "decode"
-                self.cache_lens[s] = st.true_len
-                st.inflight += 1      # the first token is in flight
-                if st.prefix_keys:
-                    for j, key in enumerate(st.prefix_keys):
-                        self.allocators[g].register(
-                            self.slot_pages[s][j], key)
-                self.stats["prefills"] += 1
-        self._inflight.append(_InFlight(
-            dec_ids=dec_ids, chunk_ids=chunk_ids, dec=dec_entries,
-            chunks=chunk_entries, step=self.step_count, dispatch_t=t1))
+        return mask_in, tab_in, len_in, chunk, dec_entries, chunk_entries
 
     def _reconcile(self, infl: _InFlight) -> None:
         """Absorb one in-flight step's sampled ids into host state: append
@@ -1690,25 +1730,34 @@ class StemEngine:
         (no newer dispatched step behind this one): those are the fetches
         that can leave the device idle — O(finished requests), not
         O(steps)."""
-        overlapped = bool(self._inflight)
-        t0 = time.perf_counter()
-        dec_ids = chunk_ids = None
-        if infl.dec:
-            dec_ids = np.asarray(infl.dec_ids)
-            if self.smesh is not None:
-                dec_ids = dec_ids.reshape(-1)
-            self.stats["id_fetches"] += 1
-        if infl.chunks:
-            chunk_ids = np.asarray(infl.chunk_ids)
-            if self.smesh is None:
-                chunk_ids = chunk_ids[None]             # (1, L)
-            self.stats["id_fetches"] += 1
-        now = time.perf_counter()
-        self.stats["sync_wait_s"] += now - t0
-        if not overlapped and (infl.dec or infl.chunks):
-            self.stats["host_syncs"] += 1
-        self.monitor.observe(infl.step, now - infl.dispatch_t)
+        with _span("engine.reconcile"):
+            overlapped = bool(self._inflight)
+            t0 = time.perf_counter()
+            dec_ids = chunk_ids = None
+            with _span("engine.wait"):
+                if infl.dec:
+                    dec_ids = np.asarray(infl.dec_ids)
+                    if self.smesh is not None:
+                        dec_ids = dec_ids.reshape(-1)
+                    self.stats["id_fetches"] += 1
+                if infl.chunks:
+                    chunk_ids = np.asarray(infl.chunk_ids)
+                    if self.smesh is None:
+                        chunk_ids = chunk_ids[None]     # (1, L)
+                    self.stats["id_fetches"] += 1
+            now = time.perf_counter()
+            self.stats["sync_wait_s"] += now - t0
+            if not overlapped and (infl.dec or infl.chunks):
+                self.stats["host_syncs"] += 1
+            self.monitor.observe(infl.step, now - infl.dispatch_t)
+            with _span("engine.emit"):
+                self._emit_async(infl, dec_ids, chunk_ids, now)
 
+    def _emit_async(self, infl: _InFlight, dec_ids, chunk_ids,
+                    now: float) -> None:
+        """Absorb one reconciled step's sampled ids: append and time-stamp
+        the tokens of requests still running, discard the lookahead of
+        finished ones, recycle."""
         for s, st in infl.dec:
             st.inflight -= 1
             if st.finished:
@@ -1769,7 +1818,9 @@ class StemEngine:
         # the already-in-flight step is untouched by the failure.
         if self.chaos:
             self.chaos.maybe_fail_step(self.step_count)
-        dec, grants = self._schedule(dec_all, pre_all, time.perf_counter())
+        with _span("engine.schedule"):
+            dec, grants = self._schedule(dec_all, pre_all,
+                                         time.perf_counter())
         if not dec and not any(grants):
             # Every grantable token is already in flight (e.g. the final
             # token of the last active request): reconcile to make
@@ -1830,19 +1881,24 @@ class StemEngine:
     def step(self) -> None:
         """One engine iteration: admit (with preemption) + shed, one guarded
         mixed batched step, recycle."""
-        # Stamp arrival wall time the first step each request is
-        # schedulable — TTFT and TTFT-SLO headroom count queueing time, so
-        # a scheduler cannot hide latency in the waiting queue.
-        now = time.perf_counter()
-        for r in self.waiting:
-            if r.arrival_step <= self.step_count and r.uid not in self._arrival_t:
-                self._arrival_t[r.uid] = now
-        self._admission_control()
-        self._admit()
-        self._guarded_step()
-        self.step_count += 1
-        if self._track_fallbacks:
-            self._refresh_fallbacks()
+        with _span("engine.step"):
+            # TTFT and TTFT-SLO headroom count queueing time from when a
+            # request could first be scheduled, so a scheduler cannot hide
+            # latency in the waiting queue: ``submit`` stamps a request
+            # that is schedulable at once, this loop one that arrives at a
+            # later step.
+            now = time.perf_counter()
+            for r in self.waiting:
+                if (r.arrival_step <= self.step_count
+                        and r.uid not in self._arrival_t):
+                    self._arrival_t[r.uid] = now
+            with _span("engine.admit"):
+                self._admission_control()
+                self._admit()
+            self._guarded_step()
+            self.step_count += 1
+            if self._track_fallbacks:
+                self._refresh_fallbacks()
 
     @property
     def pending(self) -> int:

@@ -182,6 +182,7 @@ def prefix_page_keys(tokens, budgets, page_size: int) -> list:
     return keys
 
 
+@jax.named_scope("stem.kv_write")
 def write_chunk_pages(pool: PagePool, page_table: jnp.ndarray,
                       chunk_start: jnp.ndarray, k_chunk: jnp.ndarray,
                       v_chunk: jnp.ndarray, true_len: jnp.ndarray,
@@ -241,6 +242,7 @@ def write_chunk_pages(pool: PagePool, page_table: jnp.ndarray,
     )
 
 
+@jax.named_scope("stem.kv_write")
 def append_token(pool: PagePool, page_table: jnp.ndarray,
                  cache_lens: jnp.ndarray, k_new: jnp.ndarray,
                  v_new: jnp.ndarray, cfg) -> PagePool:
@@ -326,26 +328,29 @@ def _paged_decode_xla(
     bs = cfg.block_size
     maxp = page_table.shape[1]
 
-    # Gather per-slot summaries through the page table (cheap: pooled reps).
-    kg_rows = jnp.swapaxes(pool.kg[:, page_table], 0, 1)   # (b, hk, maxp, s, d)
-    vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)   # (b, hk, maxp)
+    with jax.named_scope("stem.score"):
+        # Gather per-slot summaries through the page table (cheap: pooled
+        # reps).
+        kg_rows = jnp.swapaxes(pool.kg[:, page_table], 0, 1)  # (b,hk,maxp,s,d)
+        vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)  # (b, hk, maxp)
+        m = decode_lib.decode_block_metric(q, kg_rows, vm_rows, cfg)
 
-    m = decode_lib.decode_block_metric(q, kg_rows, vm_rows, cfg)
-    sel = decode_lib.select_decode_blocks(m, cache_lens, cfg, budget_frac)
-
-    # Logical slot index -> global page id, then fetch only selected pages.
-    gp = jnp.take_along_axis(
-        jnp.broadcast_to(page_table[:, None, None, :],
-                         (b, hk, group, maxp)),
-        sel.indices, axis=-1)                               # (b, hk, g, kmax)
+    with jax.named_scope("stem.select"):
+        sel = decode_lib.select_decode_blocks(m, cache_lens, cfg, budget_frac)
+        # Logical slot index -> global page id of each selected page.
+        gp = jnp.take_along_axis(
+            jnp.broadcast_to(page_table[:, None, None, :],
+                             (b, hk, group, maxp)),
+            sel.indices, axis=-1)                           # (b, hk, g, kmax)
 
     def fetch(kp, vp, gph):
         # kp, vp: (P, page, d); gph: (b, g, kmax) -> (b, g, kmax, page, d)
         return kp[gph], vp[gph]
 
-    gk, gv = jax.vmap(fetch, in_axes=(0, 0, 1), out_axes=1)(
-        pool.k, pool.v, gp)                                 # (b,hk,g,kmax,bs,d)
-    return decode_lib.attend_selected(q, gk, gv, sel, cache_lens, bs)
+    with jax.named_scope("stem.attend"):
+        gk, gv = jax.vmap(fetch, in_axes=(0, 0, 1), out_axes=1)(
+            pool.k, pool.v, gp)                             # (b,hk,g,kmax,bs,d)
+        return decode_lib.attend_selected(q, gk, gv, sel, cache_lens, bs)
 
 
 # The gather oracle is the registry's "xla" backend for both serving lanes

@@ -12,6 +12,7 @@ only one process may hold the TPU library at a time, and every pytest
 worker imports this file.
 """
 import os
+import re
 
 import pytest
 
@@ -71,6 +72,18 @@ def _compile(fn, *args):
 
 def _has_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _kernels_named_under_phases(hlo: str):
+    """Each Mosaic kernel of the compiled step keeps its stable name and
+    the device phase that called it in its op_name, which is what a
+    profiler trace of the chip reports for it."""
+    calls = [l for l in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert calls
+    for line in calls:
+        op = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert re.search(r"/stem\.(score|attend)/stem_paged_\w+/", op), op
 
 
 @pytest.mark.parametrize("lane", ["decode", "chunk"])
@@ -181,5 +194,6 @@ def test_unified_step_compiles(spec, monkeypatch, executor, loop):
                               spec((SLOTS,), I32), ch).compile()
         if executor == "pallas":
             _has_kernel(compiled)
+            _kernels_named_under_phases(compiled.as_text())
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
