@@ -204,6 +204,7 @@ def chunked_prefill_attention(
     policy,
     k_max: int = 0,              # static gather width (0 = max_pages)
     executor=None,               # paged backend name (None = policy.executor)
+    layer=None,                  # layer of a stacked pool (None = one layer's)
 ) -> jnp.ndarray:
     """Policy-sparse prefill attention for one chunk, straight off the page
     pool.  The chunk's own pages must already be written
@@ -213,15 +214,20 @@ def chunked_prefill_attention(
     registry — "xla" (the gather oracle below) or "pallas" (the fused
     kernels in ``kernels/paged_attn.py``).  Returns (b, hq, C, dv).
     """
+    from repro.runtime import paged as paged_lib
+
     policy = policy_lib.as_policy(policy)
     spec = policy_lib.get_paged_executor(executor or policy.executor)
-    return spec.chunk_fn(q, pool, page_table, chunk_start, budgets, policy,
-                         k_max)
+    if layer is None:
+        pool, layer = paged_lib.stack_layer(pool), 0
+    return spec.chunk_fn(q, pool, layer, page_table, chunk_start, budgets,
+                         policy, k_max)
 
 
 def _chunked_prefill_xla(
     q: jnp.ndarray,
-    pool,
+    pools,
+    layer,
     page_table: jnp.ndarray,
     chunk_start: jnp.ndarray,
     budgets: jnp.ndarray,
@@ -230,10 +236,13 @@ def _chunked_prefill_xla(
 ) -> jnp.ndarray:
     """The XLA gather backend (and the fused kernel's differential oracle):
     summary gather -> chunk metric -> selection -> page gather -> masked
-    attend, each a separate inspectable op."""
+    attend, each a separate inspectable op.  ``pools`` is the stacked pool,
+    read at ``layer`` without slicing it."""
+    from repro.runtime import paged as paged_lib
+
     policy = policy_lib.as_policy(policy)
     b, hq, c, d = q.shape
-    hk = pool.k.shape[0]
+    hk = pools.k.shape[1]
     group = hq // hk
     bs = policy.block_size
     nc = c // bs
@@ -241,8 +250,8 @@ def _chunked_prefill_xla(
 
     with jax.named_scope("stem.score"):
         # Page summaries through the page table (cheap: pooled reps only).
-        kg_rows = jnp.swapaxes(pool.kg[:, page_table], 0, 1)  # (b,hk,P,s,d)
-        vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)  # (b, hk, P)
+        kg_rows, vm_rows = paged_lib.gather_summaries(pools, layer,
+                                                      page_table)
         m = policy.chunk_scores(q, kg_rows, vm_rows)      # (b, hq, nc, P)
 
     with jax.named_scope("stem.select"):
@@ -256,11 +265,6 @@ def _chunked_prefill_xla(
                              (b, hk, group, nc, maxp)),
             idx, axis=-1)                                  # (b,hk,g,nc,kmax)
 
-    def fetch(kp, vp, gph):
-        # kp, vp: (P, page, d); gph: (b, g, nc, kmax).
-        return kp[gph], vp[gph]
-
     with jax.named_scope("stem.attend"):
-        gk, gv = jax.vmap(fetch, in_axes=(0, 0, 1), out_axes=1)(
-            pool.k, pool.v, gp)                    # (b, hk, g, nc, kmax, bs, d)
+        gk, gv = paged_lib.gather_pages(pools, layer, gp)  # (b,hk,g,nc,kmax,bs,d)
         return attend_chunk(q, gk, gv, sel, chunk_start, bs)

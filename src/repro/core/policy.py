@@ -799,12 +799,16 @@ def available_executors() -> tuple:
 class PagedExecutorSpec:
     """One execution backend for the paged serving attention lanes.
 
-    ``decode_fn(q, pool, page_table, cache_lens, policy, budget_frac)``
-    mirrors ``runtime.paged.paged_sparse_decode``;
-    ``chunk_fn(q, pool, page_table, chunk_start, budgets, policy, k_max)``
-    mirrors ``core.chunked.chunked_prefill_attention``.  Both return the
-    attention output and must be selection-identical to the "xla" oracle
-    (the differential suite in tests/test_paged_kernel.py pins this).
+    ``decode_fn(q, pools, layer, page_table, cache_lens, policy,
+    budget_frac)`` mirrors ``runtime.paged.paged_sparse_decode``;
+    ``chunk_fn(q, pools, layer, page_table, chunk_start, budgets, policy,
+    k_max)`` mirrors ``core.chunked.chunked_prefill_attention``.  ``pools``
+    is a ``PagePool`` whose leaves are stacked over layers
+    (``(n, hk, P, ...)``) and ``layer`` a traced int32 scalar: a backend
+    reads that layer's summaries and pages from the stack in place, never a
+    per-layer slice of it.  Both return the attention output and must be
+    selection-identical to the "xla" oracle (the differential suite in
+    tests/test_paged_kernel.py pins this).
 
     ``sharding`` declares the backend's tensor-parallel contract for
     mesh-sharded serving (``sharding/serving.py``): "kv-head" means both

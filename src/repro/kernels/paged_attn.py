@@ -5,10 +5,10 @@ The XLA paged executors (``runtime.paged.paged_sparse_decode`` /
 attend as separate ops, and two of those stages materialize per-step copies
 that dominate the decode hot loop:
 
-  * the summary gather ``pool.kg[:, page_table]`` — a full
+  * the summary gather of kg through the page table — a full
     (b, hk, max_pages, stride, d) copy of every visible page's pooled keys,
     rebuilt every step just to feed one einsum;
-  * the page gather ``pool.k[gp] / pool.v[gp]`` — a materialized
+  * the page gather of the selected K/V pages — a materialized
     (b, hk, g, k_max, bs, d) K/V copy before the attention einsum reads it
     exactly once.
 
@@ -16,11 +16,12 @@ This module replaces both with scalar-prefetch kernels (the PR 1
 ``block_sparse_attn.py`` machinery, generalized from a contiguous cache to
 the page pool):
 
-  * **scoring** — the page table rides as a scalar-prefetch operand and the
-    kg BlockSpec ``index_map`` resolves ``(kv_head, page_table[b, p])``
-    directly, so the DMA engine streams each page's summary tile from the
-    *pool* into VMEM; routing scores are reduced in-kernel and only the tiny
-    (b, hq, maxp) score matrix is ever materialized.
+  * **scoring** — the layer index and the page table ride as
+    scalar-prefetch operands and the kg BlockSpec ``index_map`` resolves
+    ``(layer, kv_head, page_table[b, p])`` directly, so the DMA engine
+    streams each page's summary tile from the *stacked pool* into VMEM;
+    routing scores are reduced in-kernel and only the tiny (b, hq, maxp)
+    score matrix is ever materialized.
   * **attention** — selected pages are attended flash-style with an online
     softmax.  Scalar-prefetched revisit-filled global page ids drive the
     K/V ``index_map`` (dead slots re-point at the row's last live page ->
@@ -66,6 +67,7 @@ from repro.core import metric as metric_lib
 from repro.core import policy as policy_lib
 from repro.core.selection import revisit_indices
 from repro.backend import resolve_interpret
+from repro.runtime import paged as paged_lib
 
 NEG_INF = -1e30
 
@@ -133,18 +135,19 @@ def pack_selection(indices, live, page_table):
 # Summary-resident page scoring (decode: one query row per slot)
 # ---------------------------------------------------------------------------
 
-def _score_kernel(pt_ref, q_ref, kg_ref, o_ref, *, scale):
+def _score_kernel(layer_ref, pt_ref, q_ref, kg_ref, o_ref, *, scale):
     """Routing score of one (row, page) pair straight off the pool summary.
 
     q tile (1, nc, s, d) holds the row's pooled queries (nc = 1 for decode),
-    kg tile (1, 1, s, d) is DMA'd from ``pool.kg[kv_head, page_table[b, p]]``
-    by the index map.  The (1, nc, maxp) output block stays resident across
-    the page axis; step p selects its column into the whole block, since
-    Mosaic cannot store at a dynamic lane offset.
+    kg tile (1, 1, 1, s, d) is DMA'd from
+    ``pools.kg[layer, kv_head, page_table[b, p]]`` by the index map.  The
+    (1, nc, maxp) output block stays resident across the page axis; step p
+    selects its column into the whole block, since Mosaic cannot store at a
+    dynamic lane offset.
     """
     p = pl.program_id(1)
     nc = q_ref.shape[1]
-    kg = kg_ref[0, 0].astype(jnp.float32)                     # (s, d)
+    kg = kg_ref[0, 0, 0].astype(jnp.float32)                  # (s, d)
     rows = jax.lax.broadcasted_iota(jnp.int32, (nc, 1), 0)
     col = jnp.zeros((nc, 1), jnp.float32)
     for i in range(nc):
@@ -161,32 +164,38 @@ def _score_kernel(pt_ref, q_ref, kg_ref, o_ref, *, scale):
     o_ref[0] = jnp.where(lanes == p, col, o_ref[0])
 
 
-def _score_pages(qp, kg_pool, page_table, *, group, scale, interpret,
+def _layer_operand(layer):
+    """A layer index as the (1,) int32 scalar-prefetch operand."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _score_pages(qp, kg_pool, layer, page_table, *, group, scale, interpret,
                  name):
     """qp: (b, hq, nc, s, d) pooled/permuted queries; kg_pool:
-    (hk, P, s, d) pool summaries.  Returns (b, hq, nc, maxp) fp32 routing
-    scores computed without materializing ``pool.kg[:, page_table]``."""
+    (n, hk, P, s, d) stacked pool summaries, read at ``layer``.  Returns
+    (b, hq, nc, maxp) fp32 routing scores computed without materializing
+    ``kg_pool[layer][:, page_table]``."""
     b, hq, nc, s, d = qp.shape
     maxp = page_table.shape[1]
     qr = qp.reshape(b * hq, nc, s, d)
 
-    def q_map(bh, p, pt_ref):
+    def q_map(bh, p, layer_ref, pt_ref):
         return (bh, 0, 0, 0)
 
-    def kg_map(bh, p, pt_ref):
+    def kg_map(bh, p, layer_ref, pt_ref):
         bi = bh // hq
         hi = bh % hq
-        return (hi // group, pt_ref[bi, p], 0, 0)
+        return (layer_ref[0], hi // group, pt_ref[bi, p], 0, 0)
 
-    def o_map(bh, p, pt_ref):
+    def o_map(bh, p, layer_ref, pt_ref):
         return (bh, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b * hq, maxp),
         in_specs=[
             pl.BlockSpec((1, nc, s, d), q_map),
-            pl.BlockSpec((1, 1, s, d), kg_map),
+            pl.BlockSpec((1, 1, 1, s, d), kg_map),
         ],
         out_specs=pl.BlockSpec((1, nc, maxp), o_map),
     )
@@ -199,17 +208,18 @@ def _score_pages(qp, kg_pool, page_table, *, group, scale, interpret,
         ),
         interpret=interpret,
         name=name,
-    )(page_table.astype(jnp.int32), qr, kg_pool)
+    )(_layer_operand(layer), page_table.astype(jnp.int32), qr, kg_pool)
     return out.reshape(b, hq, nc, maxp)
 
 
-def decode_page_scores(q, kg_pool, page_table, *, group,
+def decode_page_scores(q, kg_pool, layer, page_table, *, group,
                        interpret=None):
     """Kernel-backed ``metric_lib.decode_routing_scores`` against the pool.
 
-    q: (b, hq, 1, d); kg_pool: (hk, P, stride, d).  Returns (b, hk, g, maxp)
-    fp32 — bit-compatible (up to fp32 reduction order) with
-    ``decode_routing_scores(q, swapaxes(pool.kg[:, page_table], 0, 1))``.
+    q: (b, hq, 1, d); kg_pool: (n, hk, P, stride, d) stacked, read at
+    ``layer``.  Returns (b, hk, g, maxp) fp32 — bit-compatible (up to fp32
+    reduction order) with ``decode_routing_scores`` of the layer's kg rows
+    gathered through the page table.
     """
     b, hq, _, d = q.shape
     s = kg_pool.shape[-2]
@@ -218,13 +228,13 @@ def decode_page_scores(q, kg_pool, page_table, *, group,
     # single query against every summary group (the decode routing score
     # sums over all s groups).
     qp = jnp.broadcast_to(q[:, :, :, None, :], (b, hq, 1, s, d))
-    out = _score_pages(qp, kg_pool, page_table, group=group, scale=scale,
-                       interpret=resolve_interpret(interpret),
+    out = _score_pages(qp, kg_pool, layer, page_table, group=group,
+                       scale=scale, interpret=resolve_interpret(interpret),
                        name="stem_paged_decode_score")
     return out.reshape(b, hq // group, group, page_table.shape[1])
 
 
-def chunk_page_scores(q, kg_pool, page_table, *, block_size, pooling,
+def chunk_page_scores(q, kg_pool, layer, page_table, *, block_size, pooling,
                       group, interpret=None):
     """Kernel-backed ``metric_lib.chunk_routing_scores`` against the pool.
 
@@ -233,7 +243,8 @@ def chunk_page_scores(q, kg_pool, page_table, *, block_size, pooling,
     turns the paired contraction into the plain ``sum_u qp'[u] . kg[u]`` the
     shared scoring kernel computes against unpermuted in-pool summaries.
     Mean pooling reduces to the same form with the query group axis averaged
-    and broadcast.  q: (b, hq, C, d) -> (b, hq, nc, maxp) fp32.
+    and broadcast.  q: (b, hq, C, d); kg_pool: (n, hk, P, s, d) stacked,
+    read at ``layer`` -> (b, hq, nc, maxp) fp32.
     """
     b, hq, c, d = q.shape
     s = kg_pool.shape[-2]
@@ -245,8 +256,8 @@ def chunk_page_scores(q, kg_pool, page_table, *, block_size, pooling,
     else:  # mean: block mean = mean of the equal-sized group means
         qp = jnp.broadcast_to(qp.mean(axis=-2, keepdims=True), qp.shape)
         scale = 1.0 / (s * float(d) ** 0.5)
-    return _score_pages(qp, kg_pool, page_table, group=group, scale=scale,
-                        interpret=resolve_interpret(interpret),
+    return _score_pages(qp, kg_pool, layer, page_table, group=group,
+                        scale=scale, interpret=resolve_interpret(interpret),
                         name="stem_paged_chunk_score")
 
 
@@ -255,10 +266,10 @@ def chunk_page_scores(q, kg_pool, page_table, *, block_size, pooling,
 # ---------------------------------------------------------------------------
 
 def _attend_kernel(
-    gp_ref, idx_ref, cnt_ref, pos_ref,   # scalar prefetch (SMEM)
-    q_ref, k_ref, v_ref,                 # VMEM tiles
+    layer_ref, gp_ref, idx_ref, cnt_ref, pos_ref,  # scalar prefetch (SMEM)
+    q_ref, k_ref, v_ref,                           # VMEM tiles
     o_ref,
-    acc_ref, m_ref, l_ref,               # VMEM scratch
+    acc_ref, m_ref, l_ref,                         # VMEM scratch
     *,
     scale: float,
     block_k: int,
@@ -294,7 +305,7 @@ def _attend_kernel(
     def _compute():
         j = idx_ref[bi, hi, i, s]
         q = q_ref[0, 0].astype(jnp.float32) * scale       # (rows, d)
-        k = k_ref[0, 0].astype(jnp.float32)               # (bk, d)
+        k = k_ref[0, 0, 0].astype(jnp.float32)            # (bk, d)
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )                                                  # (rows, bk)
@@ -314,7 +325,7 @@ def _attend_kernel(
         p = jnp.exp(sc - m_new[:, None])
         p = jnp.where(keep, p, 0.0)
         l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        v = v_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         pv = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -327,34 +338,34 @@ def _attend_kernel(
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
-def _attend_pages(q, k_pool, v_pool, gp, idx, cnt, pos, *, block_size,
-                  causal, interpret, name):
-    """q: (b, hq, nc, rows, d); k/v_pool: (hk, P, bs, d); gp/idx:
-    (b, hq, nc, k_max) int32; cnt: (b, hq, nc) int32; pos: (b,) int32.
-    Returns (b, hq, nc, rows, dv)."""
+def _attend_pages(q, k_pool, v_pool, layer, gp, idx, cnt, pos, *,
+                  block_size, causal, interpret, name):
+    """q: (b, hq, nc, rows, d); k/v_pool: (n, hk, P, bs, d) stacked, read
+    at ``layer``; gp/idx: (b, hq, nc, k_max) int32; cnt: (b, hq, nc) int32;
+    pos: (b,) int32.  Returns (b, hq, nc, rows, dv)."""
     b, hq, nc, rows, d = q.shape
-    hk = k_pool.shape[0]
+    hk = k_pool.shape[1]
     group = hq // hk
     dv = v_pool.shape[-1]
     k_max = gp.shape[-1]
     scale = float(d) ** -0.5
     qr = q.reshape(b * hq, nc, rows, d)
 
-    def q_map(bh, i, s, gp_ref, idx_ref, cnt_ref, pos_ref):
+    def q_map(bh, i, s, layer_ref, gp_ref, idx_ref, cnt_ref, pos_ref):
         return (bh, i, 0, 0)
 
-    def kv_map(bh, i, s, gp_ref, idx_ref, cnt_ref, pos_ref):
+    def kv_map(bh, i, s, layer_ref, gp_ref, idx_ref, cnt_ref, pos_ref):
         bi = bh // hq
         hi = bh % hq
-        return (hi // group, gp_ref[bi, hi, i, s], 0, 0)
+        return (layer_ref[0], hi // group, gp_ref[bi, hi, i, s], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(b * hq, nc, k_max),
         in_specs=[
             pl.BlockSpec((1, 1, rows, d), q_map),
-            pl.BlockSpec((1, 1, block_size, d), kv_map),
-            pl.BlockSpec((1, 1, block_size, dv), kv_map),
+            pl.BlockSpec((1, 1, 1, block_size, d), kv_map),
+            pl.BlockSpec((1, 1, 1, block_size, dv), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, rows, dv), q_map),
         scratch_shapes=[
@@ -374,7 +385,7 @@ def _attend_pages(q, k_pool, v_pool, gp, idx, cnt, pos, *, block_size,
         ),
         interpret=interpret,
         name=name,
-    )(gp, idx, cnt, pos, qr, k_pool, v_pool)
+    )(_layer_operand(layer), gp, idx, cnt, pos, qr, k_pool, v_pool)
     return out.reshape(b, hq, nc, rows, dv)
 
 
@@ -382,13 +393,14 @@ def _attend_pages(q, k_pool, v_pool, gp, idx, cnt, pos, *, block_size,
 # Fused entry points (drop-in for the XLA paged executors)
 # ---------------------------------------------------------------------------
 
-def fused_paged_decode(q, pool, page_table, cache_lens, cfg,
+def fused_paged_decode(q, pools, layer, page_table, cache_lens, cfg,
                        budget_frac=None, *, interpret=None):
-    """Kernel-backed ``runtime.paged.paged_sparse_decode``.
+    """Kernel-backed ``runtime.paged._paged_decode_xla``.
 
-    Same signature and semantics; scoring and attention run as Pallas
-    kernels against the pool, selection is the shared policy code.  Falls
-    back to the XLA oracle for metric classes the scorer cannot serve.
+    Same signature and semantics (stacked pools read at ``layer``); scoring
+    and attention run as Pallas kernels against the pool, selection is the
+    shared policy code.  Falls back to the XLA oracle for metric classes
+    the scorer cannot serve.
     """
     from repro.core.decode import DEFAULT_BUDGET_FRAC, debug_assert_live_rows
     policy = policy_lib.as_policy(cfg)
@@ -398,14 +410,12 @@ def fused_paged_decode(q, pool, page_table, cache_lens, cfg,
     if kind is None:
         _note_fallback(
             "decode", f"unsupported metric {type(policy.metric).__name__}")
-        from repro.runtime import paged as paged_lib
-        return paged_lib.paged_sparse_decode(
-            q, pool, page_table, cache_lens, policy, budget_frac,
-            executor="xla")
+        return paged_lib._paged_decode_xla(
+            q, pools, layer, page_table, cache_lens, policy, budget_frac)
     interpret = resolve_interpret(interpret)
 
     b, hq, _, d = q.shape
-    hk = pool.k.shape[0]
+    hk = pools.k.shape[1]
     group = hq // hk
     maxp = page_table.shape[1]
     lens = jnp.broadcast_to(jnp.asarray(cache_lens, jnp.int32), (b,))
@@ -414,11 +424,12 @@ def fused_paged_decode(q, pool, page_table, cache_lens, cfg,
         if kind == "zero":
             m = jnp.zeros((b, hk, group, maxp), jnp.float32)
         else:
-            m = decode_page_scores(q, pool.kg, page_table, group=group,
-                                   interpret=interpret)
+            m = decode_page_scores(q, pools.kg, layer, page_table,
+                                   group=group, interpret=interpret)
             beta = getattr(policy.metric, "beta", 0.0)
             if beta:
-                vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)
+                vm_rows = paged_lib.gather_summaries(pools, layer,
+                                                     page_table)[1]
                 m = m + beta * jnp.maximum(vm_rows, 0.0)[:, :, None, :]
 
     with jax.named_scope("stem.select"):
@@ -428,7 +439,7 @@ def fused_paged_decode(q, pool, page_table, cache_lens, cfg,
     with jax.named_scope("stem.attend"):
         out = _attend_pages(
             q.reshape(b, hq, 1, 1, d),
-            pool.k, pool.v,
+            pools.k, pools.v, layer,
             gp.reshape(b, hq, 1, -1), idx.reshape(b, hq, 1, -1),
             cnt.reshape(b, hq, 1), lens,
             block_size=policy.block_size, causal=False, interpret=interpret,
@@ -436,14 +447,14 @@ def fused_paged_decode(q, pool, page_table, cache_lens, cfg,
         return out.reshape(b, hq, 1, -1)
 
 
-def fused_paged_chunk(q, pool, page_table, chunk_start, budgets, cfg,
+def fused_paged_chunk(q, pools, layer, page_table, chunk_start, budgets, cfg,
                       k_max=0, *, interpret=None):
-    """Kernel-backed ``core.chunked.chunked_prefill_attention``.
+    """Kernel-backed ``core.chunked._chunked_prefill_xla``.
 
-    Same signature and semantics (chunk pages already written to the pool);
-    selection-identical to the XLA oracle via the shared
-    ``select_chunk_blocks``.  Falls back to the oracle for metric classes or
-    poolings the scorer cannot serve.
+    Same signature and semantics (stacked pools read at ``layer``, chunk
+    pages already written); selection-identical to the XLA oracle via the
+    shared ``select_chunk_blocks``.  Falls back to the oracle for metric
+    classes or poolings the scorer cannot serve.
     """
     policy = policy_lib.as_policy(cfg)
     kind = _metric_kind(policy.metric)
@@ -454,13 +465,12 @@ def fused_paged_chunk(q, pool, page_table, chunk_start, budgets, cfg,
             "chunk",
             (f"unsupported metric {type(policy.metric).__name__}"
              if kind is None else f"unsupported pooling {pooling!r}"))
-        return chunked_lib.chunked_prefill_attention(
-            q, pool, page_table, chunk_start, budgets, policy, k_max,
-            executor="xla")
+        return chunked_lib._chunked_prefill_xla(
+            q, pools, layer, page_table, chunk_start, budgets, policy, k_max)
     interpret = resolve_interpret(interpret)
 
     b, hq, c, d = q.shape
-    hk = pool.k.shape[0]
+    hk = pools.k.shape[1]
     group = hq // hk
     bs = policy.block_size
     nc = c // bs
@@ -471,12 +481,13 @@ def fused_paged_chunk(q, pool, page_table, chunk_start, budgets, cfg,
         if kind == "zero":
             m = jnp.zeros((b, hq, nc, maxp), jnp.float32)
         else:
-            m = chunk_page_scores(q, pool.kg, page_table, block_size=bs,
-                                  pooling=pooling, group=group,
+            m = chunk_page_scores(q, pools.kg, layer, page_table,
+                                  block_size=bs, pooling=pooling, group=group,
                                   interpret=interpret)
             beta = getattr(policy.metric, "beta", 0.0)
             if beta:
-                vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)
+                vm_rows = paged_lib.gather_summaries(pools, layer,
+                                                     page_table)[1]
                 mv = jnp.repeat(vm_rows, group, axis=1)    # (b, hq, maxp)
                 m = m + beta * jnp.maximum(mv, 0.0)[..., None, :]
             m = metric_lib.group_reduce_metric(m, group, policy.group_reduce)
@@ -488,7 +499,7 @@ def fused_paged_chunk(q, pool, page_table, chunk_start, budgets, cfg,
     with jax.named_scope("stem.attend"):
         out = _attend_pages(
             q.reshape(b, hq, nc, bs, d),
-            pool.k, pool.v,
+            pools.k, pools.v, layer,
             gp, idx, cnt, start,
             block_size=bs, causal=True, interpret=interpret,
             name="stem_paged_chunk_attend")

@@ -240,11 +240,12 @@ def apply_decode_paged(
     params,
     x: jnp.ndarray,                  # (slots, 1, d) — one new token per slot
     cfg: ArchConfig,
-    pool,                            # runtime.paged.PagePool for this layer
+    pool,                            # PagePool: stacked layers or one layer's
     page_table: jnp.ndarray,         # (slots, max_pages) global page ids
     cache_lens: jnp.ndarray,         # (slots,) tokens already cached
     stem_cfg,                        # any policy spelling (see apply_full)
     *,
+    layer=None,                      # this layer's index into ``pool``
     budget_frac: float = DEFAULT_BUDGET_FRAC,
     executor: Optional[str] = None,  # paged backend (None = policy.executor)
     use_rope: bool = True,
@@ -256,7 +257,9 @@ def apply_decode_paged(
     selected pages only.  ``budget_frac=1.0`` (the shared default) is the
     dense-equivalent oracle arm (every valid page attends).  ``executor``
     picks the paged backend — "xla" gather oracle or the fused "pallas"
-    kernels.  Returns (out, new_pool)."""
+    kernels.  ``pool`` holds every layer's leaves stacked ``(n, hk, P,
+    ...)`` and this layer is written and read at ``layer`` in place; with
+    ``layer=None`` it is one layer's pool.  Returns (out, new_pool)."""
     from repro.runtime import paged as paged_lib
 
     from repro.sharding import serving as serving_lib
@@ -276,10 +279,11 @@ def apply_decode_paged(
         q = serving_lib.local_heads(q, axis=1)
         k_new = serving_lib.local_heads(k_new, axis=1)
         v_new = serving_lib.local_heads(v_new, axis=1)
-    pool = paged_lib.append_token(pool, page_table, lens, k_new, v_new, stem_cfg)
+    pool = paged_lib.append_token(pool, page_table, lens, k_new, v_new,
+                                  stem_cfg, layer=layer)
     o = paged_lib.paged_sparse_decode(q, pool, page_table, lens + 1, stem_cfg,
                                       budget_frac=budget_frac,
-                                      executor=executor)
+                                      executor=executor, layer=layer)
     with jax.named_scope("stem.attend"):
         o = serving_lib.gather_heads(o, axis=1)
     with jax.named_scope("stem.o_proj"):
@@ -291,13 +295,14 @@ def apply_chunk_paged(
     params,
     x: jnp.ndarray,                  # (slots, C, d) — one prefill chunk per slot
     cfg: ArchConfig,
-    pool,                            # runtime.paged.PagePool for this layer
+    pool,                            # PagePool: stacked layers or one layer's
     page_table: jnp.ndarray,         # (slots, max_pages) global page ids
     chunk_start: jnp.ndarray,        # (slots,) absolute chunk start positions
     true_len: jnp.ndarray,           # (slots,) true prompt lengths
     budgets: jnp.ndarray,            # (slots, C // block) absolute-row budgets
     stem_cfg,                        # any policy spelling (see apply_full)
     *,
+    layer=None,                      # this layer's index into ``pool``
     k_max: int = 0,                  # static gather width (0 = max_pages)
     executor: Optional[str] = None,  # paged backend (None = policy.executor)
     use_rope: bool = True,
@@ -310,7 +315,8 @@ def apply_chunk_paged(
     budgets and sink/local floors all at absolute positions — so any chunk
     size is selection-equivalent to one-shot prefill.  Slots without a
     chunk this step carry an all-zero page table row (writes land in the
-    trash page; outputs are ignored).  Returns (out, new_pool)."""
+    trash page; outputs are ignored).  ``pool`` and ``layer`` as in
+    ``apply_decode_paged``.  Returns (out, new_pool)."""
     from repro.core import chunked as chunked_lib
     from repro.runtime import paged as paged_lib
     from repro.sharding import serving as serving_lib
@@ -328,10 +334,11 @@ def apply_chunk_paged(
         k_new = serving_lib.local_heads(k_new, axis=1)
         v_new = serving_lib.local_heads(v_new, axis=1)
     pool = paged_lib.write_chunk_pages(pool, page_table, chunk_start, k_new,
-                                       v_new, true_len, stem_cfg)
+                                       v_new, true_len, stem_cfg, layer=layer)
     o = chunked_lib.chunked_prefill_attention(q, pool, page_table,
                                               chunk_start, budgets, stem_cfg,
-                                              k_max, executor=executor)
+                                              k_max, executor=executor,
+                                              layer=layer)
     with jax.named_scope("stem.attend"):
         o = serving_lib.gather_heads(o, axis=1)
     with jax.named_scope("stem.o_proj"):

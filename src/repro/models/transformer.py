@@ -708,8 +708,13 @@ def paged_mixed_step(params, tokens: jnp.ndarray, pools,
     ``stem.decode_lane`` / ``stem.chunk_lane`` enclose ``stem.qkv``,
     ``stem.kv_write``, ``stem.score``, ``stem.select``, ``stem.attend``,
     ``stem.o_proj`` and ``stem.mlp``; ``stem.embed`` and ``stem.head`` sit
-    outside the layers.  What the layer scan itself adds (slicing each
-    layer's pools and stacking the new ones) carries no phase.
+    outside the layers.
+
+    The layer scan carries each segment's stacked pools ``(n, hk, P, ...)``
+    with the hidden states and hands every layer its index: the lanes write
+    into and gather from the stack at ``[layer, head, page, ...]`` in
+    place, so no layer's pool is sliced out, relaid out or restacked, and
+    a donated stack is updated where it lies.
     """
     with jax.named_scope("stem.embed"):
         x = common.embed_lookup(params["embed"], tokens, cfg.jnp_dtype)
@@ -723,12 +728,11 @@ def paged_mixed_step(params, tokens: jnp.ndarray, pools,
     new_pools = []
     for si, (n, kinds) in enumerate(layer_program(cfg)):
         seg = params[f"segment{si}"]
-        pool = pools[si]
 
         def body(carry, scanned, kinds=kinds):
-            x, xc = carry
-            layer_params, pool = scanned
-            new_pool = {}
+            x, xc, pool = carry
+            layer_params, layer = scanned
+            pool = dict(pool)
             for i, k in enumerate(kinds):
                 p = layer_params[f"sub{i}"]
                 pl = pool[f"sub{i}"]
@@ -749,8 +753,8 @@ def paged_mixed_step(params, tokens: jnp.ndarray, pools,
                         mix_c, pl = attention.apply_chunk_paged(
                             p["attn"], hc, cfg, pl, chunk["page_table"],
                             chunk["start"], chunk["true_len"],
-                            chunk["budgets"], stem_cfg, k_max=chunk_k_max,
-                            executor=executor)
+                            chunk["budgets"], stem_cfg, layer=layer,
+                            k_max=chunk_k_max, executor=executor)
                         with jax.named_scope("stem.o_proj"):
                             xc = xc + mix_c
                 with jax.named_scope("stem.decode_lane"):
@@ -758,24 +762,24 @@ def paged_mixed_step(params, tokens: jnp.ndarray, pools,
                         h = common.rms_norm(x, p["norm1"])
                     mix, pl = attention.apply_decode_paged(
                         p["attn"], h, cfg, pl, page_table,
-                        cache_lens, stem_cfg, budget_frac=budget_frac,
-                        executor=executor)
+                        cache_lens, stem_cfg, layer=layer,
+                        budget_frac=budget_frac, executor=executor)
                     with jax.named_scope("stem.o_proj"):
                         x = x + mix
                     x = ffn(x)
-                new_pool[f"sub{i}"] = pl
+                pool[f"sub{i}"] = pl
                 if chunk is not None:
                     with jax.named_scope("stem.chunk_lane"):
                         xc = ffn(xc)
-            return (x, xc), new_pool
+            return (x, xc, pool), None
 
+        carry = (x, xc, pools[si])
         if n == 1:
-            (x, xc), npool = body((x, xc),
-                                  (jax.tree.map(lambda t: t[0], seg),
-                                   jax.tree.map(lambda t: t[0], pool)))
-            npool = jax.tree.map(lambda t: t[None], npool)
+            carry, _ = body(carry, (jax.tree.map(lambda t: t[0], seg), 0))
         else:
-            (x, xc), npool = jax.lax.scan(body, (x, xc), (seg, pool))
+            carry, _ = jax.lax.scan(
+                body, carry, (seg, jnp.arange(n, dtype=jnp.int32)))
+        x, xc, npool = carry
         new_pools.append(npool)
     with jax.named_scope("stem.head"):
         dec_logits = _logits(params, x, cfg)[:, 0]

@@ -15,6 +15,13 @@ Layout (one attention layer):
   kg   : (hk, num_pages, stride, d)       anti-diag group means (fp32)
   vm   : (hk, num_pages)                  max-pooled log ||V||  (fp32)
 
+The engine stacks every layer's pool along a leading ``(n_layers,)`` axis
+and the unified step carries that stack through its layer scan: the write
+paths and the paged executors take the stack plus a traced ``layer`` index
+and scatter into / gather from ``[layer, head, page, ...]`` in place, so no
+step slices a layer out of the stack or restacks it.  Each head is indexed
+explicitly, which keeps every update window inside the minor page dims.
+
 Page 0 is **reserved as the trash page**: inactive engine slots carry an
 all-zero page table, so their (masked-out) decode writes land in page 0 and
 never alias a live sequence.  The allocator never hands out page 0.
@@ -50,12 +57,36 @@ TRASH_PAGE = 0
 
 
 class PagePool(NamedTuple):
-    """One attention layer's paged KV + Stem summary storage."""
+    """Paged KV + Stem summary storage: one attention layer's leaves as
+    below, or every layer's stacked with a leading ``(n_layers,)`` axis
+    (the engine's pools; address a layer by passing ``layer``)."""
 
-    k: jnp.ndarray    # (hk, P, page, d)
-    v: jnp.ndarray    # (hk, P, page, d)
-    kg: jnp.ndarray   # (hk, P, stride, d) fp32 anti-diag group means
-    vm: jnp.ndarray   # (hk, P) fp32 max-pooled log ||V||
+    k: jnp.ndarray    # ([n,] hk, P, page, d)
+    v: jnp.ndarray    # ([n,] hk, P, page, d)
+    kg: jnp.ndarray   # ([n,] hk, P, stride, d) fp32 anti-diag group means
+    vm: jnp.ndarray   # ([n,] hk, P) fp32 max-pooled log ||V||
+
+
+def stack_layer(pool: PagePool) -> PagePool:
+    """One layer's pool as a one-layer stack (address it with layer 0)."""
+    return jax.tree.map(lambda t: t[None], pool)
+
+
+def _head_index(hk: int, ndim: int) -> jnp.ndarray:
+    """Explicit KV-head index, shaped (hk, 1, ..., 1) with ``ndim - 1``
+    trailing ones, to index a pool beside page ids of that rank.  Indexing
+    each head keeps a scatter's update window to the minor dims, so TPU
+    layout assignment never relays out the whole stack for it."""
+    return jnp.arange(hk, dtype=jnp.int32).reshape((hk,) + (1,) * (ndim - 1))
+
+
+def _page_index(layer, hk: int, pages: jnp.ndarray) -> tuple:
+    """Index of ``pages`` (a 1-D id array) in a pool leaf, broadcasting to
+    (hk, len(pages)): ``[:, pages]`` in one layer's pool (``layer`` None),
+    ``[layer, head, pages]`` with each head explicit in a stacked pool."""
+    if layer is None:
+        return (slice(None), pages)
+    return (layer, _head_index(hk, 2), pages)
 
 
 def init_pool(num_pages: int, num_kv_heads: int, page_size: int, head_dim: int,
@@ -186,7 +217,7 @@ def prefix_page_keys(tokens, budgets, page_size: int) -> list:
 def write_chunk_pages(pool: PagePool, page_table: jnp.ndarray,
                       chunk_start: jnp.ndarray, k_chunk: jnp.ndarray,
                       v_chunk: jnp.ndarray, true_len: jnp.ndarray,
-                      cfg) -> PagePool:
+                      cfg, layer=None) -> PagePool:
     """Scatter one prefill *chunk* per slot into the pool, summaries included.
 
     The chunked-prefill write path: chunk starts are block-aligned and the
@@ -205,6 +236,8 @@ def write_chunk_pages(pool: PagePool, page_table: jnp.ndarray,
     Chunk-grid overrun past the prompt's pages writes the pristine value
     (zeros + the vm floor) into reserved-but-unused spill pages — harmless,
     decode has not started for a slot still prefilling.
+    layer: None for one layer's pool, else the (traced) layer of a stacked
+    pool to write in place at ``[layer, head, page]``.
     """
     cfg = policy_lib.as_policy(cfg)
     slots, hk, c, d = k_chunk.shape
@@ -228,24 +261,24 @@ def write_chunk_pages(pool: PagePool, page_table: jnp.ndarray,
         j_abs < maxp,
         jnp.take_along_axis(page_table, jnp.minimum(j_abs, maxp - 1), axis=1),
         TRASH_PAGE)
-    flat = pids.reshape(-1)                                       # (slots*nc,)
+    idx = _page_index(layer, hk, pids.reshape(-1))
 
     def per_head(x):
-        # (slots, hk, nc, ...) -> (hk, slots*nc, ...) aligned with ``flat``.
+        # (slots, hk, nc, ...) -> (hk, slots*nc, ...) aligned with ``idx``.
         return jnp.swapaxes(x, 0, 1).reshape((hk, slots * nc) + x.shape[3:])
 
     return PagePool(
-        k=pool.k.at[:, flat].set(per_head(kp).astype(pool.k.dtype)),
-        v=pool.v.at[:, flat].set(per_head(vp).astype(pool.v.dtype)),
-        kg=pool.kg.at[:, flat].set(per_head(kg).astype(jnp.float32)),
-        vm=pool.vm.at[:, flat].set(per_head(vm).astype(jnp.float32)),
+        k=pool.k.at[idx].set(per_head(kp).astype(pool.k.dtype)),
+        v=pool.v.at[idx].set(per_head(vp).astype(pool.v.dtype)),
+        kg=pool.kg.at[idx].set(per_head(kg).astype(jnp.float32)),
+        vm=pool.vm.at[idx].set(per_head(vm).astype(jnp.float32)),
     )
 
 
 @jax.named_scope("stem.kv_write")
 def append_token(pool: PagePool, page_table: jnp.ndarray,
                  cache_lens: jnp.ndarray, k_new: jnp.ndarray,
-                 v_new: jnp.ndarray, cfg) -> PagePool:
+                 v_new: jnp.ndarray, cfg, layer=None) -> PagePool:
     """Write one new token per slot into its current page + fold summaries.
 
     The increments reproduce ``write_prefill_pages`` of the grown sequence
@@ -258,10 +291,12 @@ def append_token(pool: PagePool, page_table: jnp.ndarray,
     page_table: (slots, max_pages) global page ids; cache_lens: (slots,)
     tokens already present (the new token lands at this position).
     k_new, v_new: (slots, hk, 1, d).  Slots whose page table points at the
-    trash page (inactive) scribble page 0 harmlessly.
+    trash page (inactive) scribble page 0 harmlessly.  layer: None for one
+    layer's pool, else the (traced) layer of a stacked pool to write in
+    place at ``[layer, head, page, offset]``.
     """
     cfg = policy_lib.as_policy(cfg)
-    b = k_new.shape[0]
+    hk = k_new.shape[1]
     bs, stride = cfg.block_size, cfg.stride
     per_group = bs // stride
     lens = jnp.asarray(cache_lens, jnp.int32)
@@ -273,12 +308,13 @@ def append_token(pool: PagePool, page_table: jnp.ndarray,
     vnh = jnp.swapaxes(vn, 0, 1)
     log_norm = jnp.log(jnp.maximum(
         jnp.linalg.norm(vnh.astype(jnp.float32), axis=-1), 1e-20))
+    page = _page_index(layer, hk, pids)                     # (hk, slots)
     return PagePool(
-        k=pool.k.at[:, pids, offs].set(knh.astype(pool.k.dtype)),
-        v=pool.v.at[:, pids, offs].set(vnh.astype(pool.v.dtype)),
-        kg=pool.kg.at[:, pids, offs % stride].add(
+        k=pool.k.at[page + (offs,)].set(knh.astype(pool.k.dtype)),
+        v=pool.v.at[page + (offs,)].set(vnh.astype(pool.v.dtype)),
+        kg=pool.kg.at[page + (offs % stride,)].add(
             (knh / per_group).astype(jnp.float32)),
-        vm=pool.vm.at[:, pids].max(log_norm),
+        vm=pool.vm.at[page].max(log_norm),
     )
 
 
@@ -290,6 +326,7 @@ def paged_sparse_decode(
     cfg,
     budget_frac: float = decode_lib.DEFAULT_BUDGET_FRAC,
     executor: Optional[str] = None,
+    layer=None,
 ) -> jnp.ndarray:
     """Policy-sparse decode attention straight off the page pool.
 
@@ -300,15 +337,37 @@ def paged_sparse_decode(
     ``core/policy.py`` registry — "xla" (the gather oracle below) or
     "pallas" (the fused scalar-prefetch kernels in
     ``kernels/paged_attn.py``); None defers to ``policy.executor``.
+    ``pool`` is one layer's pool, or with ``layer`` a stacked pool read at
+    that (traced) layer.
     """
     cfg = policy_lib.as_policy(cfg)
     spec = policy_lib.get_paged_executor(executor or cfg.executor)
-    return spec.decode_fn(q, pool, page_table, cache_lens, cfg, budget_frac)
+    if layer is None:
+        pool, layer = stack_layer(pool), 0
+    return spec.decode_fn(q, pool, layer, page_table, cache_lens, cfg,
+                          budget_frac)
+
+
+def gather_summaries(pools: PagePool, layer, page_table: jnp.ndarray):
+    """Per-slot page summaries of one layer of a stacked pool through the
+    page table: kg rows (b, hk, maxp, s, d) and vm rows (b, hk, maxp)."""
+    hk = pools.kg.shape[1]
+    idx = (layer, _head_index(hk, 2)[None], page_table[:, None, :])
+    return pools.kg[idx], pools.vm[idx]
+
+
+def gather_pages(pools: PagePool, layer, gp: jnp.ndarray):
+    """Selected K/V pages of one layer of a stacked pool: gp (b, hk, ...)
+    global page ids -> k, v (b, hk, ..., page, d)."""
+    hk = pools.k.shape[1]
+    idx = (layer, _head_index(hk, gp.ndim - 1)[None], gp)
+    return pools.k[idx], pools.v[idx]
 
 
 def _paged_decode_xla(
     q: jnp.ndarray,
-    pool: PagePool,
+    pools: PagePool,
+    layer,
     page_table: jnp.ndarray,
     cache_lens: jnp.ndarray,
     cfg,
@@ -320,10 +379,11 @@ def _paged_decode_xla(
     differential oracle for the fused kernel (and the CPU-friendly default):
     every stage is a separate inspectable XLA op.  A metric registered once
     in ``core/policy.py`` serves the engine with no paged-specific code.
+    ``pools`` is the stacked pool, read at ``layer`` without slicing it.
     """
     cfg = policy_lib.as_policy(cfg)
     b, hq, _, d = q.shape
-    hk = pool.k.shape[0]
+    hk = pools.k.shape[1]
     group = hq // hk
     bs = cfg.block_size
     maxp = page_table.shape[1]
@@ -331,8 +391,7 @@ def _paged_decode_xla(
     with jax.named_scope("stem.score"):
         # Gather per-slot summaries through the page table (cheap: pooled
         # reps).
-        kg_rows = jnp.swapaxes(pool.kg[:, page_table], 0, 1)  # (b,hk,maxp,s,d)
-        vm_rows = jnp.swapaxes(pool.vm[:, page_table], 0, 1)  # (b, hk, maxp)
+        kg_rows, vm_rows = gather_summaries(pools, layer, page_table)
         m = decode_lib.decode_block_metric(q, kg_rows, vm_rows, cfg)
 
     with jax.named_scope("stem.select"):
@@ -343,13 +402,8 @@ def _paged_decode_xla(
                              (b, hk, group, maxp)),
             sel.indices, axis=-1)                           # (b, hk, g, kmax)
 
-    def fetch(kp, vp, gph):
-        # kp, vp: (P, page, d); gph: (b, g, kmax) -> (b, g, kmax, page, d)
-        return kp[gph], vp[gph]
-
     with jax.named_scope("stem.attend"):
-        gk, gv = jax.vmap(fetch, in_axes=(0, 0, 1), out_axes=1)(
-            pool.k, pool.v, gp)                             # (b,hk,g,kmax,bs,d)
+        gk, gv = gather_pages(pools, layer, gp)             # (b,hk,g,kmax,bs,d)
         return decode_lib.attend_selected(q, gk, gv, sel, cache_lens, bs)
 
 

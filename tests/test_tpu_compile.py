@@ -33,6 +33,7 @@ from repro.runtime.engine import EngineConfig
 HQ, HK, D, BS, STRIDE = 16, 8, 128, 128, 4
 SLOTS, MAXP = 4, 17                 # 4 slots x (2048 prompt + 32 decode)
 PAGES = 1 + SLOTS * MAXP
+LAYERS = 2                          # depth of the stacked pools
 CHUNK = 2 * BS
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -88,31 +89,31 @@ def _kernels_named_under_phases(hlo: str):
 
 @pytest.mark.parametrize("lane", ["decode", "chunk"])
 def test_score_pages_compiles(spec, lane):
-    kg = spec((HK, PAGES, STRIDE, D), F32)
+    kg = spec((LAYERS, HK, PAGES, STRIDE, D), F32)
     if lane == "decode":
-        fn = lambda q, kg, pt: paged_attn.decode_page_scores(
-            q, kg, pt, group=HQ // HK, interpret=False)
+        fn = lambda q, kg, layer, pt: paged_attn.decode_page_scores(
+            q, kg, layer, pt, group=HQ // HK, interpret=False)
         q, pt = spec((SLOTS, HQ, 1, D), BF16), spec((SLOTS, MAXP), I32)
     else:
-        fn = lambda q, kg, pt: paged_attn.chunk_page_scores(
-            q, kg, pt, block_size=BS, pooling="antidiag", group=HQ // HK,
-            interpret=False)
+        fn = lambda q, kg, layer, pt: paged_attn.chunk_page_scores(
+            q, kg, layer, pt, block_size=BS, pooling="antidiag",
+            group=HQ // HK, interpret=False)
         q, pt = spec((1, HQ, CHUNK, D), BF16), spec((1, MAXP), I32)
-    _has_kernel(_compile(fn, q, kg, pt))
+    _has_kernel(_compile(fn, q, kg, spec((), I32), pt))
 
 
 @pytest.mark.parametrize("lane", ["decode", "chunk"])
 def test_attend_pages_compiles(spec, lane):
     b, nc, rows, k_max = ((SLOTS, 1, 1, MAXP) if lane == "decode"
                           else (1, CHUNK // BS, BS, 2))
-    pool = spec((HK, PAGES, BS, D), BF16)
+    pool = spec((LAYERS, HK, PAGES, BS, D), BF16)
 
-    def fn(q, kp, vp, gp, idx, cnt, pos):
+    def fn(q, kp, vp, layer, gp, idx, cnt, pos):
         return paged_attn._attend_pages(
-            q, kp, vp, gp, idx, cnt, pos, block_size=BS,
+            q, kp, vp, layer, gp, idx, cnt, pos, block_size=BS,
             causal=lane == "chunk", interpret=False, name=f"attend_{lane}")
     _has_kernel(_compile(
-        fn, spec((b, HQ, nc, rows, D), BF16), pool, pool,
+        fn, spec((b, HQ, nc, rows, D), BF16), pool, pool, spec((), I32),
         spec((b, HQ, nc, k_max), I32), spec((b, HQ, nc, k_max), I32),
         spec((b, HQ, nc), I32), spec((b,), I32)))
 
@@ -150,31 +151,33 @@ def test_dense_kernels_compile(spec, kernel):
     _has_kernel(_compile(fn, *args))
 
 
-@pytest.mark.parametrize("executor,loop", [("xla", "sync"),
-                                           ("pallas", "sync"),
-                                           ("pallas", "async")])
-def test_unified_step_compiles(spec, monkeypatch, executor, loop):
-    """The engine's unified step, both signatures (mixed and decode-only),
-    at qwen3-0.6b width with the depth cut to 2 layers, for the serving
-    geometry chip_smoke.py runs (4 slots, 2048 + 32 tokens, 256-token
-    chunks).  This process's backend is the CPU, so the pallas case steers
-    the kernels to Mosaic here."""
+UNIFIED_CASES = [("xla", "sync"), ("pallas", "sync"), ("pallas", "async")]
+
+
+def _unified_step(spec, monkeypatch, executor, loop, *, layers, slots,
+                  max_prompt, max_new_tokens, donate=False):
+    """The engine's unified step at qwen3-0.6b width with the depth cut to
+    ``layers``, jitted for the described chip, and its abstract arguments
+    for both signatures: ``(step, args of the mixed step, args of the
+    decode-only step, the abstract pools)``.  ``donate`` donates the pools
+    (and the async loop's token buffer) as the engine does.  This
+    process's backend is the CPU, so the pallas case steers the kernels
+    to Mosaic here."""
     if executor == "pallas":
         monkeypatch.setattr(backend, "interpret_kernels", lambda: False)
-    cfg = configs.get_config("qwen3-0.6b").replace(num_layers=2)
+    cfg = configs.get_config("qwen3-0.6b").replace(num_layers=layers)
     bundle = registry.build(cfg)
     pol = serving_policy("stem", BS)
-    ecfg = EngineConfig.for_trace(max_slots=SLOTS, max_prompt=2048,
-                                  max_new_tokens=32, page_size=BS)
+    ecfg = EngineConfig.for_trace(max_slots=slots, max_prompt=max_prompt,
+                                  max_new_tokens=max_new_tokens, page_size=BS)
     P = ecfg.max_pages_per_slot
-    assert (P, ecfg.num_pages) == (MAXP, PAGES)
 
     def shaped(tree):
         return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
     params = shaped(jax.eval_shape(bundle.init_params,
                                    jax.random.PRNGKey(0)))
     pools = shaped(jax.eval_shape(
-        lambda: transformer.init_page_pools(cfg, PAGES, pol)))
+        lambda: transformer.init_page_pools(cfg, ecfg.num_pages, pol)))
     chunk = {"tokens": spec((1, CHUNK), I32), "page_table": spec((1, P), I32),
              "start": spec((1,), I32), "true_len": spec((1,), I32),
              "budgets": spec((1, CHUNK // BS), I32), "last": spec((1,), I32)}
@@ -182,18 +185,85 @@ def test_unified_step_compiles(spec, monkeypatch, executor, loop):
     if loop == "async":
         sampler = sampling_lib.get_sampler("greedy")
         chunk.update(slot=spec((1,), I32), emit=spec((1,), jnp.bool_))
-        lead = (spec((SLOTS,), I32), spec((SLOTS,), jnp.bool_))
+        lead = (spec((slots,), I32), spec((slots,), jnp.bool_))
     else:
-        lead = (spec((SLOTS, 1), I32),)
+        lead = (spec((slots, 1), I32),)
     step = jax.jit(steps_lib.make_unified_step(
         bundle, stem_cfg=pol, budget_frac=0.5,
         chunk_k_max=chunked_lib.chunk_budget_bound(pol, P),
-        executor=executor, sampler=sampler))
-    for ch in (chunk, None):
-        compiled = step.lower(params, pools, *lead, spec((SLOTS, P), I32),
-                              spec((SLOTS,), I32), ch).compile()
+        executor=executor, sampler=sampler),
+        donate_argnums=(((1, 2) if loop == "async" else (1,)) if donate
+                        else ()))
+    args = (params, pools) + lead + (spec((slots, P), I32),
+                                     spec((slots,), I32))
+    return step, args + (chunk,), args + (None,), pools
+
+
+@pytest.mark.parametrize("executor,loop", UNIFIED_CASES)
+def test_unified_step_compiles(spec, monkeypatch, executor, loop):
+    """The engine's unified step, both signatures (mixed and decode-only),
+    at qwen3-0.6b width with the depth cut to 2 layers, for the serving
+    geometry chip_smoke.py runs (4 slots, 2048 + 32 tokens, 256-token
+    chunks)."""
+    step, mixed, decode, pools = _unified_step(
+        spec, monkeypatch, executor, loop, layers=LAYERS, slots=SLOTS,
+        max_prompt=2048, max_new_tokens=32)
+    assert jax.tree.leaves(pools)[0].shape[2:4] == (PAGES, BS)
+    assert mixed[-3].shape == (SLOTS, MAXP)
+    for args in (mixed, decode):
+        compiled = step.lower(*args).compile()
         if executor == "pallas":
             _has_kernel(compiled)
             _kernels_named_under_phases(compiled.as_text())
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+# Result shape and opcode of one HLO instruction:
+# ``%name = f32[4,8,292]{2,1,0:T(8,128)} opcode(``.
+_INSTR = re.compile(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _pool_plumbing(hlo: str, leaf_shapes) -> list:
+    """Instructions of ``hlo`` that copy, slice or restack a whole pool
+    leaf: a ``copy``, ``dynamic-slice`` or ``dynamic-update-slice``, or a
+    fusion XLA named after one (``copy*``, ``*dynamic*slice*``), whose
+    result has a leaf's shape.  In-place scatters into the stack are
+    fusions of another name; the asynchronous memory-space moves
+    (``copy-start``/``copy-done``) are other opcodes and not counted."""
+    found = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, dims, op = m.groups()
+        shape = tuple(int(x) for x in dims.split(",")) if dims else ()
+        plumbing = (op in ("copy", "dynamic-slice", "dynamic-update-slice")
+                    or (op == "fusion"
+                        and re.match(r"copy|.*dynamic.*slice", name)))
+        if plumbing and shape in leaf_shapes:
+            found.append(f"{op} {name} {shape}")
+    return found
+
+
+@pytest.mark.parametrize("executor,loop", UNIFIED_CASES)
+def test_unified_step_updates_pools_in_place(spec, monkeypatch, executor,
+                                             loop):
+    """The unified step, compiled with its pools donated as the engine
+    donates them, writes and reads each layer in the carried stack: no op
+    copies, slices or restacks a pool leaf (k, v, kg, vm; stacked or one
+    layer's), and the step's scratch memory stays below the pools' bytes.
+    Pools of 632 MB (4 layers, 3 slots x 97 pages) are too large for the
+    compiler to stage them in on-chip memory, as a deployment's are."""
+    step, mixed, decode, pools = _unified_step(
+        spec, monkeypatch, executor, loop, layers=4, slots=3,
+        max_prompt=12288, max_new_tokens=128, donate=True)
+    leaves = jax.tree.leaves(pools)
+    shapes = {l.shape for l in leaves} | {l.shape[1:] for l in leaves}
+    pool_bytes = sum(l.size * l.dtype.itemsize for l in leaves)
+    for args in (mixed, decode):
+        compiled = step.lower(*args).compile()
+        assert not _pool_plumbing(compiled.as_text(), shapes)
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < pool_bytes
+        assert mem.alias_size_in_bytes >= pool_bytes
