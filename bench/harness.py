@@ -3,10 +3,13 @@ drive it on a wall-clock schedule, measure, then check what it served
 against the plain reference.
 
 Everything cell-specific is found by name under ``bench/``:
-``configs/<config>.json`` (model as published, serving rule, program arch),
-``traffic/<mix>.json`` (generator parameters and slots), ``cells/<cell>.json``
-(limits of the correctness check) and ``metrics/<metric>.py`` (one reader
-per metric, end-to-end and per-layer alike).
+``configs/<config>.json`` (model as published, its family, serving rule,
+program arch), ``families/<family>.py`` (the model's weights, plain
+reference and operation count; see ``families/qwen_dense.py``),
+``traffic/<mix>.json`` (generator parameters and slots),
+``cells/<cell>.json`` (limits of the correctness check) and
+``metrics/<metric>.py`` (one reader per metric, end-to-end and per-layer
+alike).
 """
 from __future__ import annotations
 
@@ -25,24 +28,30 @@ import numpy as np
 
 import devtrace
 import measure
-import reference
+import phases
+import stem_reference
 import stem_rule
 import traffic as traffic_lib
-import weights
 
 TRACE_SECONDS = 2.0
 WARMUP_PAGES = 3      # warm-up prompt: two chunk steps, then a decode-only one
 
-# Published config keys -> the program's ArchConfig fields.
-PROGRAM_FIELDS = {
-    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
-    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim", "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings", "attention_bias": "qkv_bias",
-    "qk_norm": "qk_norm", "torch_dtype": "dtype",
-}
-PROGRAM_RMS_EPS = 1e-6
+
+def load_module(path: pathlib.Path, name: str):
+    """The Python file at ``path`` as a module named ``name``."""
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(bench: pathlib.Path, name: str):
+    """The model family module ``families/<name>.py`` under ``bench``."""
+    path = pathlib.Path(bench) / "families" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no model family file {path}")
+    return load_module(path, "family_" + name)
 
 
 class Cell:
@@ -61,6 +70,10 @@ class Cell:
         self.chips = int(w["chips"])
         entry = {c["name"]: c for c in spec["configs"]}[w["config"]]
         self.config = json.loads((root / entry["file"]).read_text())
+        if "family" not in self.config:
+            raise ValueError(f"{root / entry['file']} names no family (a "
+                             f"file of {self.bench / 'families'})")
+        self.family = family(self.bench, self.config["family"])
         self.model = self.config["model"]
         self.rule = stem_rule.StemRule.from_config(self.config["serving"])
         self.traffic = json.loads(
@@ -75,21 +88,12 @@ class Cell:
         self.per_layer = [m for m in spec["per_layer"] if mine(m)]
 
     def reader(self, metric: str):
-        path = self.bench / "metrics" / f"{metric}.py"
-        mod_spec = importlib.util.spec_from_file_location(
-            "metric_" + metric.replace(".", "_").replace("-", "_"), path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
-        return mod.read
+        return load_module(self.bench / "metrics" / f"{metric}.py",
+                           "metric_" + metric).read
 
     def program_config(self, configs):
-        base = configs.get_config(self.config["program"]["arch"])
-        if self.model["rms_norm_eps"] != PROGRAM_RMS_EPS:
-            raise ValueError("the program's RMSNorm epsilon is fixed at "
-                             f"{PROGRAM_RMS_EPS}")
-        return base.replace(**{f: self.model[k]
-                               for k, f in PROGRAM_FIELDS.items()
-                               if k in self.model})
+        return self.family.program_config(
+            self.model, configs.get_config(self.config["program"]["arch"]))
 
 
 def _span(jax, name, on):
@@ -166,14 +170,27 @@ class Driver:
 
 
 class Tracer:
-    """Profiles ``TRACE_SECONDS`` in the middle of the window."""
+    """Profiles ``TRACE_SECONDS`` in the middle of the window, and keeps the
+    argument signatures of the engine's step, so that the trace's ops can
+    be named from the compiled step's HLO once the window has closed."""
 
-    def __init__(self, jax, driver, start, stop):
+    def __init__(self, jax, driver, start, stop, keep=None):
         self.jax, self.driver = jax, driver
         self.start, self.stop = start, stop
+        self.keep = keep
         self.dir = tempfile.mkdtemp(prefix="bench-trace-")
         self.state = "before"
         self.span = None
+        eng = driver.engine
+        self.step, self.signatures = eng._unified, {}
+
+        def recording(*a):
+            if (a[-1] is None) not in self.signatures:
+                self.signatures[a[-1] is None] = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(
+                        x.shape, x.dtype, sharding=x.sharding), a)
+            return self.step(*a)
+        eng._unified = recording
 
     def poll(self, now):
         if self.state == "before" and now >= self.start:
@@ -189,13 +206,24 @@ class Tracer:
             self.state = "done"
 
     def result(self):
+        """(``devtrace.reduce``, ``phases.reduce``) of the traced window;
+        each None where there is nothing to read.  With ``keep`` the loaded
+        trace is written there as JSON."""
         if self.state == "on":
             self.poll(float("inf"))
+        self.driver.engine._unified = self.step
         try:
             files = sorted(pathlib.Path(self.dir).rglob("*.xplane.pb"))
-            return devtrace.reduce(devtrace.load(str(files[0]))) if files else None
+            trace = devtrace.load(str(files[0])) if files else {"planes": []}
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
+        if any(p["name"].startswith("/device:") for p in trace["planes"]):
+            phases.name_ops(trace, [
+                phases.hlo_op_names(self.step.lower(*a).compile().as_text())
+                for a in self.signatures.values()])
+        if self.keep:
+            pathlib.Path(self.keep).write_text(json.dumps(trace))
+        return devtrace.reduce(trace), phases.reduce(trace)
 
 
 @dataclasses.dataclass
@@ -209,10 +237,11 @@ class Served:
 
 
 def run_window(jax, cell: Cell, seed: int, seconds: float, trace: bool,
-               t_start: float, out: dict):
+               t_start: float, out: dict, keep_trace: str | None = None):
     """Build, warm up, drive the window.  Fills ``out`` and returns
     ``[(Served, traffic.Item)]`` for every finished request and every one
-    still decoding at the close, once the program's state is freed."""
+    still decoding at the close, once the program's state is freed.  With
+    ``keep_trace`` the traced window's loaded trace is written there."""
     from repro import configs
     from repro.launch.serve import serving_policy
     from repro.models import registry
@@ -222,7 +251,7 @@ def run_window(jax, cell: Cell, seed: int, seconds: float, trace: bool,
     pcfg = cell.program_config(configs)
     bundle = registry.build(pcfg)
     like = bundle.abstract_params()[0]
-    params = weights.program_params(cell.model, like, seed)
+    params = cell.family.program_params(cell.model, like, seed)
     rule = cell.rule
     policy = serving_policy(cell.config["serving"]["policy"], rule.page)
     stated = dict(stride=policy.stride, sink=policy.sink_blocks,
@@ -239,7 +268,7 @@ def run_window(jax, cell: Cell, seed: int, seconds: float, trace: bool,
         max_new_tokens=tr["output"]["max"], page_size=rule.page,
         budget_frac=rule.budget_frac)
     engine = StemEngine(bundle, params, policy, ecfg)
-    vocab = cell.model["vocab_size"]
+    vocab = cell.family.vocab(cell.model)
     items = traffic_lib.generate(tr, seed, seconds, vocab)
 
     warm = np.random.default_rng(int(seed) + 1).integers(
@@ -272,19 +301,21 @@ def run_window(jax, cell: Cell, seed: int, seconds: float, trace: bool,
     tracer = None
     if trace:
         mid = opened + max(0.0, (seconds - TRACE_SECONDS) / 2)
-        tracer = Tracer(jax, drv, mid, mid + min(TRACE_SECONDS, seconds))
+        tracer = Tracer(jax, drv, mid, mid + min(TRACE_SECONDS, seconds),
+                        keep_trace)
     closed = drv.run(until=opened + seconds, record=True, tracer=tracer)
     comp1 = clock.snapshot()
     stats = {k: engine.stats[k] - stats0.get(k, 0) for k in engine.stats
              if isinstance(engine.stats[k], (int, float))}
-    out["trace"] = tracer.result() if tracer else None
+    out["trace"], out["phases"] = tracer.result() if tracer else (None, None)
     out["compiles_in_window"] = comp1["compiles"] - comp0["compiles"]
     out["compile_s_in_window"] = comp1["compile_s"] - comp0["compile_s"]
     out["record"] = {
-        "model": cell.model, "rule": rule,
+        "model": cell.model, "family": cell.family, "rule": rule,
         "peak": cell.peaks[jax.devices()[0].device_kind],
         "open": opened, "close": closed, "setup_s": out["setup_s"],
         "steps": drv.steps, "stats": stats, "trace": out["trace"],
+        "phases": out["phases"],
         "requests": {u: {"due": opened + drv.items[u].due_s,
                          "times": drv.times.get(u, [])}
                      for u in drv.submitted},
@@ -332,18 +363,19 @@ def compare(jax, cell: Cell, seed: int, sample, fp8_control: bool = False):
     {"gaps": per served token, how far its reference logit lies below the
     reference's best; "control_gaps": the same for the tokens the float8
     reference puts first (only with ``fp8_control``)}."""
-    w = jax.jit(lambda k: weights.canonical(cell.model, k))(weights.jax_key(seed))
+    fam = cell.family
+    w = jax.jit(lambda k: fam.canonical(cell.model, k))(
+        stem_reference.jax_key(seed))
     kw = dict(kmax=cell.rule.prefill_bound(cell.traffic["prompt"]["max"]),
               prompt_bucket=cell.check["prompt_bucket"])
     gaps, ctrl = [], []
     for f, it in sample:
-        ref = reference.logits(cell.model, cell.rule, w, it.prompt, f.tokens,
-                               **kw)
-        gaps.append(reference.gaps(ref, f.tokens))
+        ref = fam.logits(cell.model, cell.rule, w, it.prompt, f.tokens, **kw)
+        gaps.append(fam.gaps(ref, f.tokens))
         if fp8_control:
-            low = reference.logits(cell.model, cell.rule, w, it.prompt,
-                                   f.tokens, fp8=True, **kw)
-            ctrl.append(reference.gaps(ref, low.argmax(-1)))
+            low = fam.logits(cell.model, cell.rule, w, it.prompt, f.tokens,
+                             fp8=True, **kw)
+            ctrl.append(fam.gaps(ref, low.argmax(-1)))
     del w
     gc.collect()
     cat = lambda xs: np.concatenate(xs) if xs else np.zeros((0,))

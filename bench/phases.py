@@ -6,17 +6,18 @@ The unified step names its device phases with ``jax.named_scope``
 ``models/transformer.paged_mixed_step``) and the engine writes
 ``engine.*`` host spans (``runtime/engine.py``) on the profiler's clock.
 Each event of a device plane's ``XLA Ops`` line is one HLO instruction of
-the running program, and the innermost ``stem.*`` phase in that
-instruction's op_name metadata owns it.  A TPU trace names the
-instruction but carries no op_name (TPU v5 lite, jax 0.9.0), so
-``name_ops`` takes each op's op_name from the compiled step's HLO text.
+the running program, and the innermost ``stem.*`` scope in that
+instruction's op_name, other than a lane, owns it, whatever its name; its
+group (``GROUPS``) is that of its innermost scope that has one.  A TPU
+trace names the instruction but carries no op_name (TPU v5 lite, jax
+0.9.0), so ``name_ops`` takes each op's op_name from the compiled step's
+HLO text.
 
-``load(path)`` turns an ``.xplane.pb`` file into plain data, as
-``devtrace.load`` does, with a fourth field on each device op for its
-op_name, and the ``engine.*`` and ``bench.*`` host spans; ``reduce`` works
-on that data only, so a CPU test checks it on a small recorded trace.  Run as a
-script it is ``run.py`` with one more line before the result: ``phases:``
-and the reduction of the traced window (``--trace 1``).
+``harness.Tracer`` loads the trace (``devtrace.load``), names its ops and
+stores ``reduce`` in every traced run's record as ``phases``; ``reduce``
+works on plain data only, so a CPU test checks it on a small recorded
+trace.  Run as a script it is ``run.py`` with one more line before the
+result: ``phases:`` and the reduction of the traced window (``--trace 1``).
 
     python3 bench/phases.py --workload <cell> --seed <n> --seconds <s> --trace 1
 """
@@ -26,19 +27,16 @@ import argparse
 import bisect
 import collections
 import json
-import pathlib
 import re
 import sys
 
 import devtrace
 
-HOST_PREFIXES = ("engine.", "bench.")
-MODULES_LINE = "XLA Modules"
 STEP_MODULE = "unified_step"      # the engine's jitted step, by its name
 OP_NAME = re.compile(r'op_name="([^"]*)"')
 HLO_LINE = re.compile(r"\s*(?:ROOT )?%?([\w.-]+) = ")
+SCOPE = "stem."
 UNSCOPED = "unscoped"
-NO_SPAN = "none"
 
 # Phases of the unified step by the per-layer metric that reads them.
 GROUPS = {
@@ -48,7 +46,7 @@ GROUPS = {
               "stem.head", "stem.sample"),
     "unscoped": (UNSCOPED,),
 }
-PHASES = frozenset(p for g in GROUPS.values() for p in g if p != UNSCOPED)
+GROUP_OF = {p: g for g, ps in GROUPS.items() for p in ps if p != UNSCOPED}
 LANES = ("stem.decode_lane", "stem.chunk_lane")
 
 
@@ -71,7 +69,7 @@ def name_ops(trace: dict, programs) -> None:
     for plane in trace["planes"]:
         if not plane["name"].startswith("/device:"):
             continue
-        mods = [e for l in plane["lines"] if l["name"] == MODULES_LINE
+        mods = [e for l in plane["lines"] if l["name"] == devtrace.MODULES_LINE
                 for e in l["events"]]
         ops = sorted((e for l in plane["lines"]
                       if l["name"] == devtrace.OPS_LINE for e in l["events"]),
@@ -86,36 +84,21 @@ def name_ops(trace: dict, programs) -> None:
                 e[3] = e[3] or best.get(e[0], "")
 
 
-def load(path: str) -> dict:
-    """xplane.pb -> {"planes": [{"name", "lines": [{"name", "events"}]}]},
-    events ``[name, start_ns, duration_ns, op_name]`` with op_name ''
-    until ``name_ops``.  Device planes keep their ``XLA Ops`` and ``XLA
-    Modules`` lines; host planes keep only the ``engine.*`` and
-    ``bench.*`` spans."""
-    from jax.profiler import ProfileData
-    out = []
-    for plane in ProfileData.from_file(path).planes:
-        device = plane.name.startswith("/device:")
-        lines = []
-        for line in plane.lines:
-            if device and line.name not in (devtrace.OPS_LINE, MODULES_LINE):
-                continue
-            evs = [[devtrace.short_name(e.name), float(e.start_ns),
-                    float(e.duration_ns), ""]
-                   for e in line.events
-                   if device or e.name.startswith(HOST_PREFIXES)]
-            if evs:
-                lines.append({"name": line.name, "events": evs})
-        if lines:
-            out.append({"name": plane.name, "lines": lines})
-    return {"planes": out}
-
-
 def phase_of(op_name: str) -> str:
-    """The innermost ``stem.*`` phase of an op_name, else ``unscoped``."""
+    """The innermost ``stem.*`` scope of an op_name that is not a lane,
+    else ``unscoped``."""
     for part in reversed(op_name.split("/")):
-        if part in PHASES:
+        if part.startswith(SCOPE) and part not in LANES:
             return part
+    return UNSCOPED
+
+
+def group_of(op_name: str) -> str:
+    """The group of the innermost scope of an op_name that has one, else
+    ``unscoped``."""
+    for part in reversed(op_name.split("/")):
+        if part in GROUP_OF:
+            return GROUP_OF[part]
     return UNSCOPED
 
 
@@ -126,29 +109,14 @@ def lane_of(op_name: str) -> str:
     return UNSCOPED
 
 
-def _idle_by_span(gaps, spans):
-    """Seconds of the gaps charged, instant by instant, to the innermost
-    host span open then (the latest to start; host spans of one thread
-    nest), or to ``none``."""
-    out = collections.Counter()
-    for g0, g1 in gaps:
-        live = sorted((s, s + d, n) for n, s, d in spans
-                      if s < g1 and s + d > g0)
-        cuts = sorted({g0, g1} | {x for s, e, _ in live for x in (s, e)
-                                  if g0 < x < g1})
-        for a, b in zip(cuts, cuts[1:]):
-            open_ = [(s, -e, n) for s, e, n in live if s <= a and e >= b]
-            out[max(open_)[2] if open_ else NO_SPAN] += (b - a) * 1e-9
-    return out
-
-
 def reduce(trace: dict, top: int = 10):
     """Returns None when the trace holds no window or no device operation,
-    else {"window_s", "busy_s", "steps", "phase_s", "lane_s", "idle_s",
-    "unscoped_ops"}: device self seconds by phase and by lane, averaged
-    over the device planes; the window's step count (executions of the
-    unified step that start inside it, on the first device); idle seconds
-    of the first device by host span; the top unscoped ops by self time.
+    else {"window_s", "busy_s", "steps", "phase_s", "group_s", "lane_s",
+    "idle_s", "unscoped_ops"}: device self seconds by phase, by group and
+    by lane, averaged over the device planes; the window's step count
+    (executions of the unified step that start inside it, on the first
+    device); idle seconds of the first device by host span; the top
+    unscoped ops by self time.
     """
     host = [e for p in trace["planes"] if not p["name"].startswith("/device:")
             for l in p["lines"] for e in l["events"]]
@@ -158,7 +126,8 @@ def reduce(trace: dict, top: int = 10):
         return None
     t0 = window[0][1]
     t1 = t0 + window[0][2]
-    phase_s, lane_s, unscoped = (collections.Counter() for _ in range(3))
+    phase_s, group_s, lane_s, unscoped = (collections.Counter()
+                                          for _ in range(4))
     busy, gaps, steps = [], [], 0
     for i, plane in enumerate(devices):
         clipped = []
@@ -172,10 +141,11 @@ def reduce(trace: dict, top: int = 10):
                                       for k, (_, s, d, _) in enumerate(clipped)])
         for k, own in owned:
             name, _, _, op = clipped[k]
-            phase = phase_of(op)
-            phase_s[phase] += own * 1e-9
+            group = group_of(op)
+            phase_s[phase_of(op)] += own * 1e-9
+            group_s[group] += own * 1e-9
             lane_s[lane_of(op)] += own * 1e-9
-            if phase == UNSCOPED:
+            if group == UNSCOPED:
                 unscoped[name] += own * 1e-9
         merged = devtrace._union([(s, s + d) for _, s, d, _ in clipped])
         busy.append(sum(e - s for s, e in merged) * 1e-9)
@@ -183,7 +153,8 @@ def reduce(trace: dict, top: int = 10):
             edges = [t0] + [x for m in merged for x in m] + [t1]
             gaps = [(edges[k], edges[k + 1]) for k in range(0, len(edges), 2)
                     if edges[k + 1] > edges[k]]
-            steps = sum(1 for l in plane["lines"] if l["name"] == MODULES_LINE
+            steps = sum(1 for l in plane["lines"]
+                        if l["name"] == devtrace.MODULES_LINE
                         for e in l["events"]
                         if STEP_MODULE in e[0] and t0 <= e[1] < t1)
     if not any(busy):
@@ -191,10 +162,11 @@ def reduce(trace: dict, top: int = 10):
     n = len(devices)
     spans = [(name, s, d) for name, s, d, *_ in host
              if name != devtrace.WINDOW_SPAN]
-    idle = _idle_by_span(gaps, spans)
+    idle = devtrace.idle_by_span(gaps, spans)
     return {"window_s": (t1 - t0) * 1e-9, "busy_s": sum(busy) / n,
             "steps": steps,
             "phase_s": {k: v / n for k, v in sorted(phase_s.items())},
+            "group_s": {k: v / n for k, v in sorted(group_s.items())},
             "lane_s": {k: v / n for k, v in sorted(lane_s.items())},
             "idle_s": dict(idle.most_common()),
             "unscoped_ops": [[k, v / n] for k, v in unscoped.most_common(top)]}
@@ -206,8 +178,7 @@ def ms_per_step(rec, group: str):
     ph = rec.get("phases")
     if not ph or not ph["steps"]:
         return None
-    return sum(ph["phase_s"].get(p, 0.0) for p in GROUPS[group]) \
-        / ph["steps"] * 1e3
+    return ph["group_s"].get(group, 0.0) / ph["steps"] * 1e3
 
 
 def select_ms_per_step(rec):
@@ -227,8 +198,8 @@ def dense_ms_per_step(rec):
 
 
 def unscoped_ms_per_step(rec):
-    """Busy device time under no phase: XLA-inserted copies and the layer
-    scan's plumbing."""
+    """Busy device time under no grouped phase: ops that XLA makes without
+    an op_name (the f32 casts of gathered pages)."""
     return ms_per_step(rec, "unscoped")
 
 
@@ -243,62 +214,29 @@ def _suffix(cell) -> str:
 
 def main(argv=None, root=None, platform: str = "tpu") -> int:
     """``run.py`` with the traced window reduced by phase: prints one line
-    ``phases: {...}`` (this module's reduction plus the cell's
+    ``phases: {...}`` (the record's ``phases`` plus the cell's
     ``*_ms_per_step`` metrics) before ``run.py``'s result line, and with
     ``--keep-trace`` writes the loaded trace there as JSON."""
     import harness
-    import jax
     import run
     ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--workload", required=True)
     ap.add_argument("--keep-trace")
     mine, rest = ap.parse_known_args(argv)
+    args = run.parse(rest)
     root = root or run.ROOT
-    cell = harness.Cell(root, mine.workload)
-
-    class PhaseTracer(harness.Tracer):
-        """Keeps the argument shapes of each signature of the engine's
-        step, to name the trace's ops from the compiled step's HLO."""
-
-        def __init__(self, *args):
-            super().__init__(*args)
-            eng = self.driver.engine
-            step, self.signatures = eng._unified, {}
-
-            def recording(*a):
-                if (a[-1] is None) not in self.signatures:
-                    self.signatures[a[-1] is None] = jax.tree.map(
-                        lambda x: jax.ShapeDtypeStruct(
-                            x.shape, x.dtype, sharding=x.sharding), a)
-                return step(*a)
-            eng._unified, self.step = recording, step
-
-        def result(self):
-            if self.state == "on":
-                self.poll(float("inf"))
-            files = sorted(pathlib.Path(self.dir).rglob("*.xplane.pb"))
-            trace = load(str(files[0])) if files else {"planes": []}
-            if any(p["name"].startswith("/device:") for p in trace["planes"]):
-                name_ops(trace, [
-                    hlo_op_names(self.step.lower(*a).compile().as_text())
-                    for a in self.signatures.values()])
-            if mine.keep_trace:
-                pathlib.Path(mine.keep_trace).write_text(json.dumps(trace))
-            ph = reduce(trace)
-            line = dict(ph or {})
-            suffix = _suffix(cell)
-            for group in GROUPS:
-                name = f"{group}_ms_per_step.{suffix}"
-                line[name] = cell.reader(name)({"phases": ph})
-            print("phases: " + json.dumps(line), flush=True)
-            return super().result()
-
-    tracer, harness.Tracer = harness.Tracer, PhaseTracer
-    try:
-        return run.main(["--workload", mine.workload] + rest, root=root,
-                        platform=platform)
-    finally:
-        harness.Tracer = tracer
+    cell = harness.Cell(root, args.workload)
+    jax = run.init_jax(root, cell, platform)
+    if jax is None:
+        return 3
+    result, rec = run.measure(jax, cell, args, keep_trace=mine.keep_trace)
+    line = dict(rec["phases"] or {})
+    suffix = _suffix(cell)
+    for group in GROUPS:
+        name = f"{group}_ms_per_step.{suffix}"
+        line[name] = cell.reader(name)(rec)
+    print("phases: " + json.dumps(line), flush=True)
+    run.emit(result)
+    return 0
 
 
 if __name__ == "__main__":

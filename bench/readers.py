@@ -4,11 +4,12 @@ per-layer.
 A reader gets the run's record (``out["record"]`` of
 ``harness.run_window``): the window's open and close, every engine step's
 span and what it carried, each request's due time and token times, the
-engine's counters over the window and the reduced trace.  It returns a
-number, or None when the run has nothing to read (no step, no trace)."""
+engine's counters over the window, the reduced trace and its phases, and
+the model with its family module (``families/<family>.py``), which counts
+a step's operations and bytes.  It returns a number, or None when the run
+has nothing to read (no step, no trace)."""
 from __future__ import annotations
 
-import flops as flops_lib
 import measure
 
 
@@ -38,7 +39,7 @@ def mfu(rec):
     span = _span_s(rec)
     if span <= 0:
         return None
-    need = sum(flops_lib.step_flops(rec["model"], rec["rule"], s)
+    need = sum(rec["family"].step_flops(rec["model"], rec["rule"], s)
                for s in rec["steps"])
     return 100.0 * need / (span * rec["peak"]["bf16_flops_per_s"])
 
@@ -50,7 +51,7 @@ def hbm_share(rec):
     span = _span_s({"steps": steps})
     if span <= 0:
         return None
-    need = sum(flops_lib.step_bytes(rec["model"], rec["rule"], s)
+    need = sum(rec["family"].step_bytes(rec["model"], rec["rule"], s)
                for s in steps)
     return 100.0 * need / (span * rec["peak"]["hbm_bytes_per_s"])
 

@@ -56,26 +56,25 @@ def init_jax(root: pathlib.Path, cell, platform: str):
     return jax
 
 
-def main(argv=None, root: pathlib.Path = ROOT, platform: str = "tpu") -> int:
+def parse(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def measure(jax, cell, args, keep_trace: str | None = None):
+    """One run of the cell: (the result line's object, the run's record)."""
     import harness
-    cell = harness.Cell(root, args.workload)
-    jax = init_jax(root, cell, platform)
-    if jax is None:
-        return 3
     device = {"platform": jax.devices()[0].platform,
               "kind": jax.devices()[0].device_kind,
               "count": len(jax.devices())}
 
     out: dict = {}
     served = harness.run_window(jax, cell, args.seed, args.seconds,
-                                bool(args.trace), T_START, out)
+                                bool(args.trace), T_START, out, keep_trace)
     finished = [p for p in served if p[0].done]
     print(f"window: compiles {out['compiles_in_window']} "
           f"({out['compile_s_in_window']:.3f} s), steps "
@@ -86,7 +85,7 @@ def main(argv=None, root: pathlib.Path = ROOT, platform: str = "tpu") -> int:
     chk = cell.check
     sample = harness.pick_sample(served, args.seed, chk["sample_tokens"],
                                  chk["sample_requests"])
-    wrong = harness.served_counts_wrong(served, cell.model["vocab_size"])
+    wrong = harness.served_counts_wrong(served, cell.family.vocab(cell.model))
     failed = sum(1 for f, _ in finished if f.error is not None)
     gaps = harness.compare(jax, cell, args.seed, sample)["gaps"]
     gap = float(gaps.mean()) if len(gaps) else float("inf")
@@ -116,11 +115,28 @@ def main(argv=None, root: pathlib.Path = ROOT, platform: str = "tpu") -> int:
         result["breakdown"] = {"device_ops": tr["device_ops"],
                                "idle_gaps": tr["idle_gaps"]}
     result["checks"] = checks
-    for name, c in checks.items():
+    return result, rec
+
+
+def emit(result: dict) -> None:
+    """The compared numbers beside their limits, closing standard error,
+    then the result line, closing standard output."""
+    for name, c in result["checks"].items():
         print(f"check {name}: {c['value']} (limit {c['limit']})",
               file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(result), flush=True)
+
+
+def main(argv=None, root: pathlib.Path = ROOT, platform: str = "tpu") -> int:
+    args = parse(argv)
+    import harness
+    cell = harness.Cell(root, args.workload)
+    jax = init_jax(root, cell, platform)
+    if jax is None:
+        return 3
+    result, _ = measure(jax, cell, args)
+    emit(result)
     return 0
 
 

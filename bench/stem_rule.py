@@ -1,8 +1,8 @@
 """The benchmark's own copy of Stem's serving budget rules.
 
-Two rules decide how many key pages a query keeps, and the FLOP counter
-(``flops.py``) and the plain reference (``reference.py``) both read them
-from here, never from the program:
+Two rules decide how many key pages a query keeps, and each family's
+operation count and plain reference (``families/<family>.py``, with
+``stem_reference.py``) read them from here, never from the program:
 
 * prefill (Token Position-Decay, paper Eq. 3 at block granularity): query
   block row ``i`` of a prompt padded to ``nk`` pages keeps

@@ -1,5 +1,6 @@
 """The benchmark's copy of Stem's budget rule agrees with the program's
-policy for every cell's settings, and the FLOP counter adds up."""
+policy for every cell's settings, and the Qwen family's FLOP count adds
+up."""
 from __future__ import annotations
 
 import json
@@ -8,9 +9,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import flops
+import harness
 import stem_rule
 from conftest import BENCH
+
+qwen_dense = harness.family(BENCH, "qwen_dense")
 
 
 def _configs():
@@ -62,7 +65,7 @@ def test_linear_params_match_program_tree():
     layer = like["segment0"]["sub0"]
     mats = [x for x in jax.tree.leaves({k: layer[k] for k in ("attn", "ffn")})
             if len(x.shape) > 2]
-    assert flops.linear_params(cfg["model"]) == sum(
+    assert qwen_dense.linear_params(cfg["model"]) == sum(
         int(np.prod(x.shape)) for x in mats)
 
 
@@ -72,14 +75,14 @@ def test_chunk_flops_count_real_tokens_and_kept_keys():
              "head_dim": 4, "intermediate_size": 16, "vocab_size": 10,
              "tie_word_embeddings": True, "torch_dtype": "float32"}
     rule = stem_rule.StemRule(page=4, stride=2)
-    lin = 2.0 * flops.linear_params(model)
+    lin = 2.0 * qwen_dense.linear_params(model)
     # A 6-token prompt, one chunk of 8: rows 0 and 1, budgets [1, 2].
-    got = flops.chunk_flops(model, rule, 6, 0, 8, completes=True)
+    got = qwen_dense.chunk_flops(model, rule, 6, 0, 8, completes=True)
     keys = [1, 2, 3, 4] + [4 + 1, 4 + 2]
     score = (1 + 2) * rule.stride * 2.0 * 4 * 2
     want = lin * 6 + 4.0 * 2 * 4 * sum(keys) + score + 2.0 * 8 * 10
     assert got == pytest.approx(want)
     # A decode token at position 9 (3 valid pages, budget 2): page 0 and
     # its own page up to position 9.
-    assert flops.decode_flops(model, rule, 9) == pytest.approx(
+    assert qwen_dense.decode_flops(model, rule, 9) == pytest.approx(
         lin + 2.0 * 8 * 10 + 2 * (4.0 * 4 * (4 + 2) + 2.0 * 4 * 3))

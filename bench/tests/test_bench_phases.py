@@ -66,6 +66,12 @@ def test_phase_of_takes_the_innermost_phase():
     assert phases.phase_of("") == "unscoped"
     assert phases.lane_of(f"{STEP}/stem.chunk_lane/stem.qkv/dot") \
         == "stem.chunk_lane"
+    # a scope no group names is keyed by its own name, and its ops count
+    # in the group of the innermost scope around it that has one
+    moe = f"{STEP}/stem.decode_lane/stem.mlp/stem.moe/dot"
+    assert phases.phase_of(moe) == "stem.moe"
+    assert phases.group_of(moe) == "dense"
+    assert phases.group_of(f"{STEP}/stem.new/add") == "unscoped"
 
 
 def test_self_time_by_phase_steps_and_idle_spans():
@@ -187,6 +193,31 @@ def test_recorded_trace(path):
     assert set(want["phase_s"]) - {"unscoped"}, "no op carried a phase"
     idle = got["window_s"] - got["busy_s"]
     assert got["idle_s"].get("none", 0.0) < 0.1 * idle
+
+
+def test_nested_new_scope_keeps_the_group_sums():
+    """Every op of the recorded trace under ``stem.mlp`` gains an inner
+    scope no group names: ``phase_s`` keys it by that name, and each
+    group's sum stays as recorded."""
+    path = DATA / "phases_v5lite_decode4b.json.gz"
+    with gzip.open(path, "rt") as f:
+        trace = json.load(f)
+    nested = 0
+    for plane in trace["planes"]:
+        for line in plane["lines"]:
+            for e in line["events"]:
+                if len(e) > 3 and e[3].split("/")[-2:-1] == ["stem.mlp"]:
+                    e[3] = e[3].replace("stem.mlp/", "stem.mlp/stem.moe/")
+                    nested += 1
+    assert nested
+    got = phases.reduce(trace)
+    want = json.loads(path.with_suffix("").with_suffix(".expected")
+                      .read_text())["phase_s"]
+    assert got["phase_s"]["stem.moe"] > 0
+    assert got["phase_s"].get("stem.mlp", 0.0) < want["stem.mlp"]
+    sums = {g: sum(want.get(p, 0.0) for p in ps)
+            for g, ps in phases.GROUPS.items()}
+    assert got["group_s"] == pytest.approx(sums, rel=1e-9)
 
 
 def test_script_prints_phases_before_the_result(monkeypatch, capsys,

@@ -1,6 +1,8 @@
 """The plain reference against the serving program at a tiny size on the
 CPU, both in float32: every logit the engine computed for a served token
-matches.  The float8 control lies far from both."""
+matches.  The float8 control lies far from both.  The Qwen family's
+reference computes, bit for bit, the logits recorded from the reference
+before it was split into a family module and ``stem_reference.py``."""
 from __future__ import annotations
 
 import collections
@@ -12,9 +14,10 @@ import pytest
 
 import conftest
 import harness
-import reference
+import stem_reference
 import traffic
-import weights
+
+DATA = conftest.BENCH / "tests" / "data"
 
 
 @pytest.fixture(scope="module")
@@ -29,8 +32,8 @@ def served(tiny_root):
     cell = harness.Cell(tiny_root, "tiny.tinyoff")
     seed = 5
     bundle = registry.build(cell.program_config(configs))
-    params = weights.program_params(cell.model, bundle.abstract_params()[0],
-                                    seed)
+    params = cell.family.program_params(cell.model,
+                                        bundle.abstract_params()[0], seed)
     tr, rule = cell.traffic, cell.rule
     eng = StemEngine(bundle, params, serving_policy("stem", rule.page),
                      EngineConfig.for_trace(
@@ -63,15 +66,16 @@ def served(tiny_root):
                 rows[st.req.uid].append(
                     last["chunk"][0] if n0 == 0 else last["dec"][s])
     done = {f.uid: f for f in eng.finished}
-    w = jax.jit(lambda k: weights.canonical(cell.model, k))(weights.jax_key(seed))
+    w = jax.jit(lambda k: cell.family.canonical(cell.model, k))(
+        stem_reference.jax_key(seed))
     return cell, w, [(it, done[it.uid].tokens, np.stack(rows[it.uid]))
                      for it in items]
 
 
 def _ref(cell, w, it, tokens, rule=None, fp8=False):
-    return reference.logits(cell.model, rule or cell.rule, w, it.prompt,
-                            tokens, kmax=cell.rule.prefill_bound(300),
-                            prompt_bucket=256, fp8=fp8)
+    return cell.family.logits(cell.model, rule or cell.rule, w, it.prompt,
+                              tokens, kmax=cell.rule.prefill_bound(300),
+                              prompt_bucket=256, fp8=fp8)
 
 
 def test_reference_matches_served_logits(served):
@@ -79,7 +83,7 @@ def test_reference_matches_served_logits(served):
     for it, tokens, prog in reqs:
         ref = _ref(cell, w, it, tokens)
         np.testing.assert_allclose(prog[:, :ref.shape[1]], ref, atol=2e-5)
-        assert harness.widest(reference.gaps(ref, tokens)) == 0.0
+        assert harness.widest(cell.family.gaps(ref, tokens)) == 0.0
 
 
 def test_reference_sees_the_decode_budget(served):
@@ -102,3 +106,20 @@ def test_fp8_control_is_far_from_the_program(served):
         prog_err = max(prog_err, float(np.abs(prog[:, :ref.shape[1]] - ref).max()))
         ctrl_err = max(ctrl_err, float(np.abs(low - ref).max()))
     assert ctrl_err > 100 * prog_err
+
+
+def test_qwen_dense_logits_match_recorded(tiny_root):
+    """Logits of four requests on the tiny configuration (weights from seed
+    5; one with the float8 control), recorded from the reference as it
+    stood before the Qwen family moved into ``families/qwen_dense.py``,
+    come out bit for bit the same."""
+    cell = harness.Cell(tiny_root, "tiny.tinyoff")
+    w = jax.jit(lambda k: cell.family.canonical(cell.model, k))(
+        stem_reference.jax_key(5))
+    rec = np.load(DATA / "qwen_dense_tiny_logits.npz")
+    for i in range(4):
+        got = cell.family.logits(
+            cell.model, cell.rule, w, rec[f"prompt{i}"], rec[f"served{i}"],
+            kmax=cell.rule.prefill_bound(300), prompt_bucket=256,
+            fp8=bool(rec[f"fp8{i}"]))
+        np.testing.assert_array_equal(got, rec[f"logits{i}"])

@@ -15,8 +15,17 @@ import harness
 import run
 
 
-def _main(monkeypatch, capsys, root, workload, trace=0, seed=31):
+def _main(monkeypatch, capsys, root, workload, trace=0, seed=31,
+          records=None):
     monkeypatch.setattr(run, "init_jax", conftest.cpu_jax)
+    if records is not None:
+        measure = run.measure
+
+        def keep(*args, **kw):
+            result, rec = measure(*args, **kw)
+            records.append(rec)
+            return result, rec
+        monkeypatch.setattr(run, "measure", keep)
     rc = run.main(["--workload", workload, "--seed", str(seed),
                    "--seconds", "2", "--trace", str(trace)], root=root,
                    platform="cpu")
@@ -27,7 +36,9 @@ def _main(monkeypatch, capsys, root, workload, trace=0, seed=31):
 @pytest.mark.parametrize("workload,trace", [("tiny.tinyoff", 0),
                                             ("tiny.tinychat", 1)])
 def test_tiny_run_is_correct(monkeypatch, capsys, tiny_root, workload, trace):
-    rc, res, err = _main(monkeypatch, capsys, tiny_root, workload, trace)
+    records = []
+    rc, res, err = _main(monkeypatch, capsys, tiny_root, workload, trace,
+                         records=records)
     assert rc == 0
     assert list(res)[:5] == ["correct", "attempted", "failed", "metrics",
                              "device"]
@@ -39,6 +50,10 @@ def test_tiny_run_is_correct(monkeypatch, capsys, tiny_root, workload, trace):
     if trace:
         assert "breakdown" in res and "busy_s" in res["device"]
         assert {"host_ms_per_step.chat", "mfu.chat"} <= names
+        # the phase split of every traced run; the CPU's trace has no
+        # device plane, so there is nothing to split
+        ph = records[0]["phases"]
+        assert ph is None or isinstance(ph["phase_s"], dict)
     else:
         assert {"prompt_tokens_per_s", "setup_s"} <= names
     for m in res["metrics"].values():

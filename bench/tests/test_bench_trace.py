@@ -45,6 +45,23 @@ def test_union_window_and_gaps():
     assert r["idle_gaps"][2] == ["bench.step", pytest.approx(0.005)]
 
 
+def test_idle_gaps_go_to_the_innermost_span():
+    """The program's ``engine.*`` spans nest inside ``bench.step``; a gap
+    is named after the innermost span that holds most of it."""
+    tr = {"planes": [
+        {"name": "/host:CPU", "lines": [{"name": "python", "events": [
+            _ev("bench.traced", 0, 100), _ev("bench.step", 0, 100),
+            _ev("engine.step", 1, 98), _ev("engine.inputs", 10, 30),
+            _ev("engine.wait", 60, 8)]}]},
+        {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": [
+            _ev("fusion.1", 0, 5), _ev("fusion.2", 50, 10),
+            _ev("fusion.3", 65, 35)]}]}]}
+    r = devtrace.reduce(tr)
+    # gaps: 5..50 (inputs 30 of it, engine.step 15), 60..65 (wait)
+    assert r["idle_gaps"] == [["engine.inputs", pytest.approx(0.045)],
+                              ["engine.wait", pytest.approx(0.005)]]
+
+
 def test_busy_is_averaged_over_devices():
     host = {"name": "/host:CPU", "lines": [{"name": "p", "events": [
         _ev("bench.traced", 0, 10)]}]}
