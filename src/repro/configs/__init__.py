@@ -22,6 +22,7 @@ from repro.configs.base import (
     RGLRUConfig,
     RunShape,
     SSDConfig,
+    YarnScaling,
     shapes_for,
 )
 
@@ -32,6 +33,7 @@ from repro.configs.qwen1_5_4b import CONFIG as QWEN1_5_4B
 from repro.configs.recurrentgemma_2b import CONFIG as RECURRENTGEMMA_2B
 from repro.configs.arctic_480b import CONFIG as ARCTIC_480B
 from repro.configs.deepseek_v3_671b import CONFIG as DEEPSEEK_V3_671B
+from repro.configs.deepseek_v2_lite import CONFIG as DEEPSEEK_V2_LITE
 from repro.configs.mamba2_370m import CONFIG as MAMBA2_370M
 from repro.configs.whisper_medium import CONFIG as WHISPER_MEDIUM
 from repro.configs.pixtral_12b import CONFIG as PIXTRAL_12B
@@ -58,7 +60,7 @@ ASSIGNED = {
         PIXTRAL_12B,
     )
 }
-EXTRA = {c.name: c for c in (LLAMA31_8B, QWEN3_8B)}
+EXTRA = {c.name: c for c in (LLAMA31_8B, QWEN3_8B, DEEPSEEK_V2_LITE)}
 ALL = {**ASSIGNED, **EXTRA}
 
 
@@ -105,8 +107,9 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
             first_dense_d_ff=128 if cfg.moe.first_k_dense else 0,
         )
     if cfg.mla is not None:
-        kw["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16, rope_head_dim=8,
-                              nope_head_dim=16, v_head_dim=16)
+        kw["mla"] = MLAConfig(
+            q_lora_rank=None if cfg.mla.q_lora_rank is None else 32,
+            kv_lora_rank=16, rope_head_dim=8, nope_head_dim=16, v_head_dim=16)
         kw["head_dim"] = 24
     if cfg.encdec is not None:
         kw["encdec"] = EncDecConfig(encoder_layers=2, encoder_frames=16)

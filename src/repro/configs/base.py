@@ -25,15 +25,29 @@ class MoEConfig:
     first_k_dense: int = 0           # deepseek: first 3 layers are dense
     first_dense_d_ff: int = 0
     router_aux_weight: float = 0.001
+    norm_topk_prob: bool = True      # renormalise the top-k gate weights
+    routed_scaling_factor: float = 1.0   # scale of the routed experts' sum
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536   # None: queries straight from q_proj
     kv_lora_rank: int = 512
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (``rope_scaling`` with ``type: yarn``, as in
+    DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +88,7 @@ class ArchConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnScaling] = None
     tie_embeddings: bool = True
     norm: str = "rms"             # rms | layer
     moe: Optional[MoEConfig] = None

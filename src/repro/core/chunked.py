@@ -191,6 +191,37 @@ def attend_chunk(
     return o.reshape(b, hq, c, dv).astype(q.dtype)
 
 
+def attend_latent_chunk(
+    qt: jnp.ndarray,           # (b, h, C, D) absorbed chunk queries
+    pages: jnp.ndarray,        # (b, nc, k_max, bs, D) kept latent pages
+    sel: ChunkSelection,       # indices / live (b, 1, nc, k_max), head-shared
+    chunk_start: jnp.ndarray,  # (b,)
+    block_size: int,
+    rank: int,
+) -> jnp.ndarray:
+    """``attend_chunk`` over latent pages, absorbed (see
+    ``core.decode.attend_latent``): each query-block row's one page set
+    serves every head.  Returns (b, h, C, rank) float32."""
+    b, h, c, d = qt.shape
+    bs = block_size
+    nc = c // bs
+    qb = qt.reshape(b, h, nc, bs, d).astype(pages.dtype)
+    s = jnp.einsum("bhnqd,bnkcd->bhnqkc", qb, pages,
+                   preferred_element_type=jnp.float32) * (d ** -0.5)
+    tok_pos = sel.indices[:, 0][..., None] * bs + jnp.arange(bs)  # (b,nc,k,bs)
+    q_pos = chunk_start[:, None, None] + (jnp.arange(nc) * bs)[None, :, None] \
+        + jnp.arange(bs)[None, None, :]                          # (b, nc, bs_q)
+    keep = (tok_pos[:, :, None] <= q_pos[..., None, None]) \
+        & sel.live[:, 0][:, :, None, :, None]                    # (b,nc,q,k,bs)
+    keep = keep[:, None]
+    s = jnp.where(keep, s, NEG_INF)
+    p = jax.nn.softmax(s.reshape(b, h, nc, bs, -1), axis=-1).reshape(s.shape)
+    p = jnp.where(keep, p, 0.0)
+    o = jnp.einsum("bhnqkc,bnkcr->bhnqr", p.astype(pages.dtype),
+                   pages[..., :rank], preferred_element_type=jnp.float32)
+    return o.reshape(b, h, c, rank)
+
+
 # ---------------------------------------------------------------------------
 # The full phase: metric -> select -> gather -> attend
 # ---------------------------------------------------------------------------

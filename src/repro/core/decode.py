@@ -184,6 +184,36 @@ def attend_selected(
     return o.reshape(b, hq, 1, gv.shape[-1]).astype(q.dtype)
 
 
+def attend_latent(
+    qt: jnp.ndarray,           # (b, h, 1, D) absorbed queries, D latent width
+    pages: jnp.ndarray,        # (b, k_max, bs, D) kept latent pages, one set
+    sel: DecodeSelection,      # indices / live (b, 1, 1, k_max)
+    cache_lens: jnp.ndarray,   # (b,)
+    block_size: int,
+    rank: int,
+) -> jnp.ndarray:
+    """Absorbed MLA attention over one shared set of kept latent pages:
+    logits ``qt . [c, k_rope] / sqrt(D)``, PV in the latent (the first
+    ``rank`` entries, ``c``).  Both products take the pages in their stored
+    dtype with float32 accumulation, so no page is copied per head or cast.
+    Zero live slots give a zero row (``attend_selected``'s contract).
+    Returns (b, h, 1, rank) float32."""
+    b, h, _, d = qt.shape
+    bs = block_size
+    lens = jnp.broadcast_to(jnp.asarray(cache_lens, jnp.int32), (b,))
+    s = jnp.einsum("bhd,bnkd->bhnk", qt[:, :, 0].astype(pages.dtype), pages,
+                   preferred_element_type=jnp.float32) * (d ** -0.5)
+    idx, live = sel.indices[:, 0, 0], sel.live[:, 0, 0]     # (b, k_max)
+    tok_pos = idx[..., None] * bs + jnp.arange(bs)           # (b, k_max, bs)
+    keep = ((tok_pos < lens[:, None, None]) & live[..., None])[:, None]
+    s = jnp.where(keep, s, NEG_INF)
+    p = jax.nn.softmax(s.reshape(b, h, -1), axis=-1).reshape(s.shape)
+    p = jnp.where(keep, p, 0.0)
+    o = jnp.einsum("bhnk,bnkr->bhr", p.astype(pages.dtype), pages[..., :rank],
+                   preferred_element_type=jnp.float32)
+    return o[:, :, None]
+
+
 def sparse_decode_attention(
     q: jnp.ndarray,           # (b, hq, 1, d) — one new query token
     cache_k: jnp.ndarray,     # (b, hk, L, d)

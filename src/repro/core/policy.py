@@ -648,6 +648,32 @@ class SparsityPolicy:
 
 
 # ---------------------------------------------------------------------------
+# Head-shared selection over a latent (MLA) cache
+# ---------------------------------------------------------------------------
+
+# A latent cache holds one "KV head" that every query head reads through its
+# absorbed projections, so the heads of a slot share one page set: each
+# head's Stem score is computed as usual and the scores are reduced over the
+# heads before the budgeted top-k.  "mean" is the policy's own
+# ``group_reduce`` where it shares already, else this default.
+LATENT_GROUP_REDUCE = "mean"
+
+
+def latent_group_reduce(policy: "SparsityPolicy") -> str:
+    """How the query heads over a latent cache share their page scores."""
+    return (policy.group_reduce if policy.group_reduce != "none"
+            else LATENT_GROUP_REDUCE)
+
+
+def share_heads(m: jnp.ndarray, axis: int, mode: str) -> jnp.ndarray:
+    """Reduce page scores over the head ``axis`` ("mean" or "max"), keeping
+    it as size 1."""
+    if mode == "max":
+        return m.max(axis=axis, keepdims=True)
+    return m.mean(axis=axis, keepdims=True)
+
+
+# ---------------------------------------------------------------------------
 # Registries
 # ---------------------------------------------------------------------------
 

@@ -148,7 +148,8 @@ def make_unified_step(bundle: registry.ModelBundle, *, stem_cfg,
                       sampler=None):
     """The engine's single step: (params, pools, tokens (S,1),
     page_table (S,P), cache_lens (S,), chunk) ->
-    (decode logits (S, vocab), chunk logits (S, vocab) | None, pools).
+    (decode logits (S, vocab), chunk logits (S, vocab) | None, pools,
+    moe counts (2,)).
 
     One mixed batch of decode tokens + prefill chunks per call
     (``transformer.paged_mixed_step``).  With fixed S/P/C the chunked
@@ -173,7 +174,8 @@ def make_unified_step(bundle: registry.ModelBundle, *, stem_cfg,
     With ``sampler`` (``runtime/sampling.py``) the builder returns the
     SAMPLED signature instead — the async engine's step: (params, pools,
     token_buf (S,), dec_mask (S,), page_table, cache_lens, chunk) ->
-    (dec_ids (S,), chunk_ids (L,) | None, token_buf', pools).  Sampling
+    (dec_ids (S,), chunk_ids (L,) | None, token_buf', pools, moe counts).
+    Sampling
     runs inside the trace (``transformer.paged_sampled_step``), decode
     inputs come from the device-resident ``token_buf``, and the only
     per-step transfer left is the int32 id arrays.  Under the mesh,
@@ -233,9 +235,9 @@ def make_unified_step(bundle: registry.ModelBundle, *, stem_cfg,
 
     def _decode_body(params, pools, tokens, page_table, cache_lens):
         def one(pools_g, tokens_g, table_g, lens_g):
-            dec, _, new_pools = mixed_step(params, tokens_g, pools_g,
-                                           table_g, lens_g, None)
-            return dec, new_pools
+            dec, _, new_pools, counts = mixed_step(params, tokens_g, pools_g,
+                                                   table_g, lens_g, None)
+            return dec, new_pools, counts
         return jax.vmap(one)(pools, tokens, page_table, cache_lens)
 
     # Sampled twins: same lane structure, id outputs + the fed-back token
@@ -251,9 +253,9 @@ def make_unified_step(bundle: registry.ModelBundle, *, stem_cfg,
     def _decode_sampled_body(params, pools, buf, mask, page_table,
                              cache_lens):
         def one(pools_g, buf_g, mask_g, table_g, lens_g):
-            ids, _, new_buf, new_pools = sampled_step(
+            ids, _, new_buf, new_pools, counts = sampled_step(
                 params, buf_g, mask_g, pools_g, table_g, lens_g, None)
-            return ids, new_buf, new_pools
+            return ids, new_buf, new_pools, counts
         return jax.vmap(one)(pools, buf, mask, page_table, cache_lens)
 
     # check_rep=False: outputs are bitwise replicated over tp by
@@ -263,11 +265,11 @@ def make_unified_step(bundle: registry.ModelBundle, *, stem_cfg,
         smapped_mixed = shard_map(
             _mixed_body, mesh=smesh.mesh,
             in_specs=(REP, POOL, GRP, GRP, GRP, GRP),
-            out_specs=(GRP, GRP, POOL), check_rep=False)
+            out_specs=(GRP, GRP, POOL, GRP), check_rep=False)
         smapped_decode = shard_map(
             _decode_body, mesh=smesh.mesh,
             in_specs=(REP, POOL, GRP, GRP, GRP),
-            out_specs=(GRP, POOL), check_rep=False)
+            out_specs=(GRP, POOL, GRP), check_rep=False)
 
         def unified_step(params, pools, tokens, page_table, cache_lens,
                          chunk=None):
@@ -278,9 +280,9 @@ def make_unified_step(bundle: registry.ModelBundle, *, stem_cfg,
             # models/attention.py for exactly this trace.
             with serving_lib.head_sharding(smesh.tp):
                 if chunk is None:
-                    dec, new_pools = smapped_decode(params, pools, tokens,
-                                                    page_table, cache_lens)
-                    return dec, None, new_pools
+                    dec, new_pools, counts = smapped_decode(
+                        params, pools, tokens, page_table, cache_lens)
+                    return dec, None, new_pools, counts
                 return smapped_mixed(params, pools, tokens, page_table,
                                      cache_lens, chunk)
         return unified_step
@@ -288,11 +290,11 @@ def make_unified_step(bundle: registry.ModelBundle, *, stem_cfg,
     smapped_mixed_s = shard_map(
         _mixed_sampled_body, mesh=smesh.mesh,
         in_specs=(REP, POOL, GRP, GRP, GRP, GRP, GRP),
-        out_specs=(GRP, GRP, GRP, POOL), check_rep=False)
+        out_specs=(GRP, GRP, GRP, POOL, GRP), check_rep=False)
     smapped_decode_s = shard_map(
         _decode_sampled_body, mesh=smesh.mesh,
         in_specs=(REP, POOL, GRP, GRP, GRP, GRP),
-        out_specs=(GRP, GRP, POOL), check_rep=False)
+        out_specs=(GRP, GRP, POOL, GRP), check_rep=False)
 
     def unified_sampled(params, pools, token_buf, dec_mask, page_table,
                         cache_lens, chunk=None):
@@ -300,10 +302,10 @@ def make_unified_step(bundle: registry.ModelBundle, *, stem_cfg,
             on_trace()
         with serving_lib.head_sharding(smesh.tp):
             if chunk is None:
-                ids, buf, new_pools = smapped_decode_s(
+                ids, buf, new_pools, counts = smapped_decode_s(
                     params, pools, token_buf, dec_mask, page_table,
                     cache_lens)
-                return ids, None, buf, new_pools
+                return ids, None, buf, new_pools, counts
             return smapped_mixed_s(params, pools, token_buf, dec_mask,
                                    page_table, cache_lens, chunk)
     return unified_sampled

@@ -67,8 +67,8 @@ def _project(params, x, cfg: ArchConfig, positions, *, use_rope: bool = True):
         q = common.rms_norm(q, params["q_norm"])
         k = common.rms_norm(k, params["k_norm"])
     if use_rope:
-        q = common.apply_rope(q, positions, cfg.rope_theta)
-        k = common.apply_rope(k, positions, cfg.rope_theta)
+        q = common.apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = common.apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     return q, k, v
 
 

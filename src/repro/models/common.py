@@ -98,16 +98,58 @@ def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,
 # Rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(scale) + 1``."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0) -> jnp.ndarray:
-    """x: (..., seq, head_dim); positions: (seq,) or (batch, seq)."""
+def _yarn_correction_dim(rotations: float, dim: int, theta: float,
+                         max_pos: int) -> float:
+    return dim * math.log(max_pos / (rotations * 2 * math.pi)) / (
+        2 * math.log(theta))
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    """Inverse frequencies of the ``head_dim / 2`` rotated pairs.  With
+    ``scaling`` (``configs.base.YarnScaling``) they blend YaRN's
+    interpolated ``f / factor`` and extrapolated ``f`` over the linear ramp
+    between the correction dims of ``beta_fast`` and ``beta_slow``
+    rotations (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
+    power = theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    if scaling is None:
+        return 1.0 / power
+    lo = max(math.floor(_yarn_correction_dim(
+        scaling.beta_fast, head_dim, theta, scaling.original_max_position)), 0)
+    hi = min(math.ceil(_yarn_correction_dim(
+        scaling.beta_slow, head_dim, theta, scaling.original_max_position)),
+        head_dim - 1)
+    hi = hi + 0.001 if lo == hi else hi
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    extra = 1.0 - ramp                                # 1: keep f, 0: f / factor
+    return 1.0 / (scaling.factor * power) * (1.0 - extra) + 1.0 / power * extra
+
+
+def rope_mscale(scaling) -> float:
+    """Scale of YaRN's cos/sin tables: mscale over mscale_all_dim (1 when
+    they are equal, as in DeepSeek-V2)."""
+    if scaling is None:
+        return 1.0
+    return (yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0,
+               scaling=None) -> jnp.ndarray:
+    """x: (..., seq, head_dim); positions: (seq,) or (batch, seq).  Rotates
+    adjacent pairs ``(2i, 2i+1)`` by frequency ``i``; ``scaling`` as in
+    ``rope_freqs``."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                      # (d/2,)
+    freqs = rope_freqs(d, theta, scaling)             # (d/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, d/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        cos, sin = cos * rope_mscale(scaling), sin * rope_mscale(scaling)
     # Broadcast over head dims: x is (b, h, s, d); angles (s, d/2) or (b, s, d/2).
     while cos.ndim < x.ndim:
         cos, sin = cos[..., None, :, :], sin[..., None, :, :]

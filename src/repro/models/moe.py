@@ -22,6 +22,10 @@ prefix sums and every shape is static; overflow drops (Switch-style).
 Covers: plain top-k routed experts, deepseek (+1 shared expert, first-k
 dense in the assembly), arctic (+parallel dense residual FFN),
 Switch load-balance aux loss.
+
+Serving (``apply_dropless``, the paged step's FFN) is different: it drops
+nothing.  The (token, expert) pairs are sorted by expert and multiplied
+group by group, so every pair the router picks is computed at any load.
 """
 from __future__ import annotations
 
@@ -53,6 +57,22 @@ def init(ini: common.Initializer, d_model: int, moe: MoEConfig, activation: str)
     return p
 
 
+def _gate(params, x, moe: MoEConfig):
+    """Softmax router and greedy top-k: (gates (..., E), weights (..., K),
+    experts (..., K)).  The top-k weights are renormalised to sum to 1 only
+    with ``norm_topk_prob``; otherwise they are scaled by
+    ``routed_scaling_factor`` (DeepSeek-V2's ``MoEGate``)."""
+    router_logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                               params["router"])
+    gates = jax.nn.softmax(router_logits, axis=-1)
+    weights, experts = jax.lax.top_k(gates, moe.top_k)
+    if moe.norm_topk_prob:
+        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    else:
+        weights = weights * moe.routed_scaling_factor
+    return gates, weights, experts
+
+
 def _route(params, x, moe: MoEConfig):
     """Top-k routing + per-row positions.  All local/elementwise.
 
@@ -63,10 +83,7 @@ def _route(params, x, moe: MoEConfig):
     b, s, d = x.shape
     E, K = moe.num_experts, moe.top_k
     capacity = max(1, int(moe.capacity_factor * s * K / E))
-    router_logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), params["router"])
-    gates = jax.nn.softmax(router_logits, axis=-1)
-    weights, experts = jax.lax.top_k(gates, K)                  # (b, s, K)
-    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    gates, weights, experts = _gate(params, x, moe)             # (b, s, K)
 
     flat_e = experts.reshape(b, s * K)                          # slot-major
     order = jnp.argsort(flat_e, axis=1, stable=True)
@@ -182,6 +199,43 @@ def apply(
     ce = jnp.zeros((E,), jnp.float32).at[experts.reshape(-1)].add(1.0) / (b_ * s_)
     aux = (me * ce).sum() * E * moe.router_aux_weight
     return y, aux
+
+
+def apply_dropless(params: dict, x: jnp.ndarray, moe: MoEConfig,
+                   activation: str, real: Optional[jnp.ndarray] = None):
+    """The serving FFN: every (token, expert) pair the router picks is
+    computed, at any load.  The pairs are sorted by expert and each expert
+    multiplies its own contiguous rows (``jax.lax.ragged_dot``), so nothing
+    is dropped and no capacity is set.  The shared experts (and arctic's
+    dense residual) run on every token.
+
+    x: (b, s, d); ``real`` (b, s) marks the tokens that are not padding
+    (all by default).  Returns (y (b, s, d), load (E,) int32: the pairs of
+    real tokens routed to each expert)."""
+    with jax.named_scope("stem.moe"):
+        b, s, d = x.shape
+        E, K = moe.num_experts, moe.top_k
+        act = jax.nn.silu if activation == "silu" else jax.nn.gelu
+        xf = x.reshape(b * s, d)
+        _, weights, experts = _gate(params, xf, moe)          # (T, K)
+        flat = experts.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+        xs = jnp.take(xf, order // K, axis=0)                  # (T*K, d)
+        h = (act(jax.lax.ragged_dot(xs, params["w_gate"], sizes))
+             * jax.lax.ragged_dot(xs, params["w_up"], sizes))
+        ys = jax.lax.ragged_dot(h, params["w_down"], sizes)
+        ys = jnp.take(ys, jnp.argsort(order), axis=0)          # (token, k) order
+        y = jnp.einsum("tkd,tk->td", ys.reshape(b * s, K, d).astype(jnp.float32),
+                       weights).astype(x.dtype).reshape(b, s, d)
+        if moe.shared_experts:
+            y = y + mlp.apply(params["shared"], x, activation)
+        if moe.residual_dense:
+            y = y + mlp.apply(params["residual"], x, activation)
+        mask = (jnp.ones((b * s,), jnp.int32) if real is None
+                else real.reshape(-1).astype(jnp.int32))
+        load = jnp.zeros((E,), jnp.int32).at[flat].add(jnp.repeat(mask, K))
+    return y, load
 
 
 def expert_flops_per_token(d_model: int, moe: MoEConfig) -> float:

@@ -546,12 +546,18 @@ def prefill(params, batch: dict, cfg: ArchConfig, *, max_len: int,
 # Paged serving: page pools + batched ragged decode (runtime/engine.py)
 # ---------------------------------------------------------------------------
 
-PAGED_KINDS = ("dense", "moe")   # attention sub-layers the paged engine serves
+# Attention sub-layers the paged engine serves: global attention over K/V
+# pages, and MLA over latent pages (``runtime.paged.LatentPool``).
+PAGED_KINDS = ("dense", "moe", "mla_dense", "mla_moe")
+MLA_KINDS = ("mla_dense", "mla_moe")
+MOE_KINDS = ("moe", "mla_moe")
 
 
 def assert_paged_servable(cfg: ArchConfig) -> None:
-    """The paged engine needs every mixer to be causal global attention —
-    ring/windowed, MLA-latent, and recurrent states have no page layout."""
+    """The paged engine needs every mixer to be causal global attention,
+    over K/V pages or over MLA's latent pages (one latent per token, read
+    absorbed by every head) — ring/windowed and recurrent states have no
+    page layout."""
     for _, kinds in layer_program(cfg):
         for k in kinds:
             if k not in PAGED_KINDS:
@@ -560,9 +566,14 @@ def assert_paged_servable(cfg: ArchConfig) -> None:
                     f"(arch {cfg.name})")
 
 
+def has_latent_pages(cfg: ArchConfig) -> bool:
+    return any(k in MLA_KINDS for _, kinds in layer_program(cfg) for k in kinds)
+
+
 def init_page_pools(cfg: ArchConfig, num_pages: int, stem_cfg, smesh=None):
     """Per-layer page pools, stacked along the scan axis like init_caches.
-    Every attention layer gets its own (hk, P, page, d) pool; the page
+    Every attention layer gets its own (hk, P, page, d) pool, an MLA layer
+    a (1, P, page, latent width) latent pool; the page
     table (slot -> pages) is shared across layers and lives in the engine.
     ``stem_cfg`` accepts any policy spelling (page = policy block).
 
@@ -573,12 +584,19 @@ def init_page_pools(cfg: ArchConfig, num_pages: int, stem_cfg, smesh=None):
 
     stem_cfg = policy_lib.as_policy(stem_cfg)
     assert_paged_servable(cfg)
+
+    def pool(kind):
+        if kind in MLA_KINDS:
+            return paged_lib.init_latent_pool(
+                num_pages, stem_cfg.block_size, mla.latent_width(cfg),
+                stem_cfg.stride, cfg.jnp_dtype)
+        return paged_lib.init_pool(
+            num_pages, cfg.num_kv_heads, stem_cfg.block_size, cfg.head_dim,
+            stem_cfg.stride, cfg.jnp_dtype)
+
     pools = []
     for n, kinds in layer_program(cfg):
-        one = {f"sub{i}": paged_lib.init_pool(
-                   num_pages, cfg.num_kv_heads, stem_cfg.block_size,
-                   cfg.head_dim, stem_cfg.stride, cfg.jnp_dtype)
-               for i, _ in enumerate(kinds)}
+        one = {f"sub{i}": pool(k) for i, k in enumerate(kinds)}
         pools.append(jax.tree.map(
             lambda t: jnp.broadcast_to(t, (n,) + t.shape), one))
     if smesh is not None:
@@ -603,6 +621,10 @@ def prefill_kv_pages(params, tokens: jnp.ndarray, true_len: jnp.ndarray,
     """
     from repro.runtime import paged as paged_lib
 
+    if has_latent_pages(cfg):
+        raise NotImplementedError(
+            f"{cfg.name}: latent pages are written by the chunked step; "
+            "monolithic prefill has no latent page write")
     stem_cfg = policy_lib.as_policy(stem_cfg)
     logits, caches = prefill(params, {"tokens": tokens}, cfg,
                              max_len=tokens.shape[1], stem_cfg=stem_cfg,
@@ -665,7 +687,7 @@ def prefill_kv_pages_suffix(params, tokens: jnp.ndarray,
     }
     # Idle decode lane: zero page table -> its masked write lands in the
     # trash page, exactly like an inactive engine slot.
-    _, chunk_logits, new_pools = paged_mixed_step(
+    _, chunk_logits, new_pools, _ = paged_mixed_step(
         params, jnp.zeros((1, 1), jnp.int32), pools,
         jnp.zeros((1, page_row.shape[0]), jnp.int32),
         jnp.zeros((1,), jnp.int32), cfg, stem_cfg=stem_cfg,
@@ -701,14 +723,25 @@ def paged_mixed_step(params, tokens: jnp.ndarray, pools,
                  prompt's final token, for chunks that finish a prefill).
 
     Returns (decode logits (slots, vocab),
-             chunk logits (L, vocab) | None, new pools).
+             chunk logits (L, vocab) | None, new pools,
+             moe counts (2,) int32).  ``moe counts`` are, summed over the
+    MoE layers, the distinct routed experts the step's real tokens hit and
+    the most (token, expert) pairs routed to one expert of a layer (both
+    lanes together; zeros for a model without experts).
 
     Device phases carry ``jax.named_scope`` names, so each op of the
     compiled step is owned by one phase in a profiler trace: the lanes
     ``stem.decode_lane`` / ``stem.chunk_lane`` enclose ``stem.qkv``,
     ``stem.kv_write``, ``stem.score``, ``stem.select``, ``stem.attend``,
     ``stem.o_proj`` and ``stem.mlp``; ``stem.embed`` and ``stem.head`` sit
-    outside the layers.
+    outside the layers.  Inside them: ``stem.mla_decode`` /
+    ``stem.mla_chunk`` (in ``stem.attend``: the latent gather, absorbed
+    attention and ``W_UV``) and ``stem.moe`` (in ``stem.mlp``: router,
+    dispatch, routed and shared experts, combine).
+
+    MLA layers (``mla_dense`` / ``mla_moe``) run ``mla.apply_*_paged``
+    over their latent pools; MoE layers run ``moe.apply_dropless``, which
+    computes every routed (token, expert) pair.
 
     The layer scan carries each segment's stacked pools ``(n, hk, P, ...)``
     with the hidden states and hands every layer its index: the lanes write
@@ -725,32 +758,43 @@ def paged_mixed_step(params, tokens: jnp.ndarray, pools,
         if cfg.embed_scale_flag:
             x = x * (cfg.d_model ** 0.5)
             xc = None if xc is None else xc * (cfg.d_model ** 0.5)
+    # Tokens that are not padding, for the MoE counters: decode slots with
+    # a page table (idle slots point at the trash page) and chunk positions
+    # inside their prompt.
+    dec_real = (page_table[:, 0] > 0)[:, None]
+    chunk_real = None
+    if chunk is not None:
+        chunk_real = (chunk["start"][:, None] + jnp.arange(xc.shape[1])
+                      < chunk["true_len"][:, None])
     new_pools = []
+    moe_counts = jnp.zeros((2,), jnp.int32)
     for si, (n, kinds) in enumerate(layer_program(cfg)):
         seg = params[f"segment{si}"]
 
         def body(carry, scanned, kinds=kinds):
-            x, xc, pool = carry
+            x, xc, pool, counts = carry
             layer_params, layer = scanned
             pool = dict(pool)
             for i, k in enumerate(kinds):
                 p = layer_params[f"sub{i}"]
                 pl = pool[f"sub{i}"]
+                lanes = mla if k in MLA_KINDS else attention
 
-                def ffn(h, k=k, p=p):
+                def ffn(h, real, k=k, p=p):
+                    """(residual + FFN, pairs routed to each expert)."""
                     with jax.named_scope("stem.mlp"):
                         h2 = common.rms_norm(h, p["norm2"])
-                        if k == "moe":
-                            y, _ = moe.apply(p["ffn"], h2, cfg.moe,
-                                             cfg.activation)
-                            return h + y
-                        return h + mlp.apply(p["ffn"], h2, cfg.activation)
+                        if k in MOE_KINDS:
+                            y, load = moe.apply_dropless(
+                                p["ffn"], h2, cfg.moe, cfg.activation, real)
+                            return h + y, load
+                        return h + mlp.apply(p["ffn"], h2, cfg.activation), 0
 
                 if chunk is not None:
                     with jax.named_scope("stem.chunk_lane"):
                         with jax.named_scope("stem.qkv"):
                             hc = common.rms_norm(xc, p["norm1"])
-                        mix_c, pl = attention.apply_chunk_paged(
+                        mix_c, pl = lanes.apply_chunk_paged(
                             p["attn"], hc, cfg, pl, chunk["page_table"],
                             chunk["start"], chunk["true_len"],
                             chunk["budgets"], stem_cfg, layer=layer,
@@ -760,26 +804,30 @@ def paged_mixed_step(params, tokens: jnp.ndarray, pools,
                 with jax.named_scope("stem.decode_lane"):
                     with jax.named_scope("stem.qkv"):
                         h = common.rms_norm(x, p["norm1"])
-                    mix, pl = attention.apply_decode_paged(
+                    mix, pl = lanes.apply_decode_paged(
                         p["attn"], h, cfg, pl, page_table,
                         cache_lens, stem_cfg, layer=layer,
                         budget_frac=budget_frac, executor=executor)
                     with jax.named_scope("stem.o_proj"):
                         x = x + mix
-                    x = ffn(x)
+                    x, load = ffn(x, dec_real)
                 pool[f"sub{i}"] = pl
                 if chunk is not None:
                     with jax.named_scope("stem.chunk_lane"):
-                        xc = ffn(xc)
-            return (x, xc, pool), None
+                        xc, load_c = ffn(xc, chunk_real)
+                    load = load + load_c
+                if k in MOE_KINDS:
+                    counts = counts + jnp.stack(
+                        [(load > 0).sum(), load.max()]).astype(jnp.int32)
+            return (x, xc, pool, counts), None
 
-        carry = (x, xc, pools[si])
+        carry = (x, xc, pools[si], moe_counts)
         if n == 1:
             carry, _ = body(carry, (jax.tree.map(lambda t: t[0], seg), 0))
         else:
             carry, _ = jax.lax.scan(
                 body, carry, (seg, jnp.arange(n, dtype=jnp.int32)))
-        x, xc, npool = carry
+        x, xc, npool, moe_counts = carry
         new_pools.append(npool)
     with jax.named_scope("stem.head"):
         dec_logits = _logits(params, x, cfg)[:, 0]
@@ -787,7 +835,7 @@ def paged_mixed_step(params, tokens: jnp.ndarray, pools,
         if chunk is not None:
             xl = jnp.take_along_axis(xc, chunk["last"][:, None, None], axis=1)
             chunk_logits = _logits(params, xl, cfg)[:, 0]
-    return dec_logits, chunk_logits, new_pools
+    return dec_logits, chunk_logits, new_pools, moe_counts
 
 
 def paged_sampled_step(params, token_buf: jnp.ndarray, pools,
@@ -813,9 +861,9 @@ def paged_sampled_step(params, token_buf: jnp.ndarray, pools,
     instead of a vocab-sized logits row.
 
     Returns (dec_ids (slots,) int32, chunk_ids (L,) int32 | None,
-             new token_buf (slots,), new pools).
+             new token_buf (slots,), new pools, moe counts (2,) int32).
     """
-    dec_logits, chunk_logits, new_pools = paged_mixed_step(
+    dec_logits, chunk_logits, new_pools, moe_counts = paged_mixed_step(
         params, token_buf[:, None], pools, page_table, cache_lens, cfg,
         stem_cfg=stem_cfg, budget_frac=budget_frac, chunk=chunk,
         chunk_k_max=chunk_k_max, executor=executor)
@@ -831,7 +879,7 @@ def paged_sampled_step(params, token_buf: jnp.ndarray, pools,
             slots = token_buf.shape[0]
             target = jnp.where(chunk["emit"], chunk["slot"], slots)
             new_buf = new_buf.at[target].set(chunk_ids, mode="drop")
-    return dec_ids, chunk_ids, new_buf, new_pools
+    return dec_ids, chunk_ids, new_buf, new_pools, moe_counts
 
 
 def paged_decode_step(params, tokens: jnp.ndarray, pools,
@@ -841,7 +889,7 @@ def paged_decode_step(params, tokens: jnp.ndarray, pools,
     """One token for every engine slot against the paged Stem KV cache —
     the decode-only view of ``paged_mixed_step`` (kept for direct callers).
     Returns (logits (slots, vocab), new pools)."""
-    logits, _, new_pools = paged_mixed_step(
+    logits, _, new_pools, _ = paged_mixed_step(
         params, tokens, pools, page_table, cache_lens, cfg,
         stem_cfg=stem_cfg, budget_frac=budget_frac, chunk=None,
         executor=executor)
@@ -855,9 +903,13 @@ def decode_step(params, tokens: jnp.ndarray, caches, cfg: ArchConfig, *,
     With ``stem_cfg`` the attention sub-layers decode POLICY-SPARSE over
     the contiguous cache (summarize + select per step) — the fixed-batch
     reference for the paged engine's sparse decode.  Only global-attention
-    architectures support it (same constraint as paged serving)."""
+    architectures support it."""
     if stem_cfg is not None:
-        assert_paged_servable(cfg)
+        for _, kinds in layer_program(cfg):
+            if any(k not in ("dense", "moe") for k in kinds):
+                raise NotImplementedError(
+                    f"sparse contiguous decode serves global attention only, "
+                    f"got {kinds} (arch {cfg.name})")
     x = common.embed_lookup(params["embed"], tokens, cfg.jnp_dtype)
     if cfg.embed_scale_flag:
         x = x * (cfg.d_model ** 0.5)

@@ -395,6 +395,7 @@ class _InFlight:
     ``finished`` flag marks stale entries for free discard."""
     dec_ids: object               # (T,) / (G, S) int32 device array
     chunk_ids: object             # (L,) / (G, L) int32 device array | None
+    moe_counts: object            # (2,) / (G, 2) int32 device array
     dec: list                     # [(slot, _SlotState), ...]
     chunks: list                  # [(g, lane, slot, _SlotState, completes)]
     step: int                     # engine step at dispatch
@@ -456,6 +457,10 @@ class StemEngine:
                                max(1, self.token_budget // self.chunk_size))
         if not ecfg.monolithic_prefill:
             chunked_lib.validate_chunked_policy(self.policy)
+        elif transformer.has_latent_pages(self.cfg):
+            raise NotImplementedError(
+                f"{self.cfg.name}: latent (MLA) pages are written by the "
+                "chunked step; disable monolithic_prefill")
 
         S, P = ecfg.max_slots, ecfg.max_pages_per_slot
         # Serving mesh: dp independent slot groups (flat slot ids
@@ -505,8 +510,15 @@ class StemEngine:
                       "host_syncs": 0, "id_fetches": 0,
                       "lookahead_discards": 0, "pallas_fallbacks": 0,
                       "restore_bytes": 0, "admissions": 0,
+                      "moe_experts_hit": 0, "moe_max_expert_load": 0,
                       "dispatch_s": 0.0, "sync_wait_s": 0.0,
                       "queue_wait_s": 0.0}
+        # Models with experts fetch the step's MoE counters with its
+        # results (``_fetch``): distinct routed experts hit and the busiest
+        # expert's pairs, each summed over MoE layers and steps.
+        self._moe = any(k in transformer.MOE_KINDS
+                        for _, kinds in transformer.layer_program(self.cfg)
+                        for k in kinds)
         self._slot_ever_used = [False] * T
         self._seq: dict = {}                   # uid -> submission order
         self._arrival_t: dict = {}             # uid -> when it could first
@@ -554,17 +566,28 @@ class StemEngine:
             # dispatch donated calls asynchronously and keep both.
             donate = (() if jax.default_backend() == "cpu"
                       and (os.cpu_count() or 1) > 1 else (1, 2))
-            self._unified = jax.jit(steps_lib.make_unified_step(
+            step = jax.jit(steps_lib.make_unified_step(
                 bundle, stem_cfg=self.policy, budget_frac=ecfg.budget_frac,
                 chunk_k_max=k_bound, executor=ecfg.executor,
                 on_trace=_count("traces"), smesh=self.smesh,
                 sampler=self.sampler), donate_argnums=donate)
         else:
-            self._unified = jax.jit(steps_lib.make_unified_step(
+            step = jax.jit(steps_lib.make_unified_step(
                 bundle, stem_cfg=self.policy, budget_frac=ecfg.budget_frac,
                 chunk_k_max=k_bound, executor=ecfg.executor,
                 on_trace=_count("traces"), smesh=self.smesh),
                 donate_argnums=(1,))
+        # ``_unified`` returns the step's results without its last one, the
+        # MoE counters, which wait in ``_moe_counts`` until the results are
+        # fetched: code that wraps ``_unified`` (the benchmark's tracer and
+        # tests) sees the results it always did, and ``lower`` still works.
+        self._moe_counts = None
+
+        def unified(*args):
+            *out, self._moe_counts = step(*args)
+            return tuple(out)
+        unified.lower = step.lower
+        self._unified = unified
         if self.smesh is not None:
             # Group-vmapped page-management jits: every argument gains a
             # leading (dp,) axis — non-target groups ride along with
@@ -1468,6 +1491,7 @@ class StemEngine:
         with _span("engine.dispatch"):
             dec_logits, chunk_logits, self.pools = self._unified(
                 self.params, self.pools, dec_in, tab_in, len_in, chunk)
+            moe_counts = self._moe_counts
         t_fetch = time.perf_counter()
         self.stats["dispatch_s"] += t_fetch - t_dispatch
         # The ONLY per-step host syncs, mesh or not: one logits fetch per
@@ -1475,12 +1499,13 @@ class StemEngine:
         # mesh adds none).
         with _span("engine.wait"):
             if dec:
-                dec_logits = np.asarray(dec_logits)
+                dec_logits, moe_counts = self._fetch(dec_logits, moe_counts)
                 if self.smesh is not None:
                     dec_logits = dec_logits.reshape(self.total_slots, -1)
                 self.stats["host_syncs"] += 1
             if any_grant:
-                chunk_logits = np.asarray(chunk_logits)
+                chunk_logits, moe_counts = self._fetch(chunk_logits,
+                                                       moe_counts)
                 if self.smesh is None:
                     chunk_logits = chunk_logits[None]   # (1, L, vocab)
                 self.stats["host_syncs"] += 1
@@ -1492,6 +1517,18 @@ class StemEngine:
         with _span("engine.emit"):
             self._emit_sync(dec, grants, dec_logits, chunk_logits, now)
         return True
+
+    def _fetch(self, result, moe_counts):
+        """``np.asarray(result)``.  A model with experts takes the step's
+        MoE counters in the same transfer and adds them to ``stats``;
+        returns (result, the counters still to take: None once taken)."""
+        if moe_counts is None or not self._moe:
+            return np.asarray(result), moe_counts
+        result, counts = jax.device_get((result, moe_counts))
+        counts = np.asarray(counts).reshape(-1, 2).sum(0)
+        self.stats["moe_experts_hit"] += int(counts[0])
+        self.stats["moe_max_expert_load"] += int(counts[1])
+        return np.asarray(result), None
 
     def _sync_inputs(self, dec: list, grants: list):
         """The synchronous step's device inputs: decode tokens, page-table
@@ -1619,6 +1656,7 @@ class StemEngine:
             dec_ids, chunk_ids, self.token_buf, self.pools = self._unified(
                 self.params, self.pools, self.token_buf, mask_in, tab_in,
                 len_in, chunk)
+            moe_counts = self._moe_counts
         t1 = time.perf_counter()
         self.stats["dispatch_s"] += t1 - t0
         self.stats["step_calls"] += 1
@@ -1641,8 +1679,8 @@ class StemEngine:
                             self.slot_pages[s][j], key)
                 self.stats["prefills"] += 1
         self._inflight.append(_InFlight(
-            dec_ids=dec_ids, chunk_ids=chunk_ids, dec=dec_entries,
-            chunks=chunk_entries, step=self.step_count, dispatch_t=t1))
+            dec_ids=dec_ids, chunk_ids=chunk_ids, moe_counts=moe_counts,
+            dec=dec_entries, chunks=chunk_entries, step=self.step_count, dispatch_t=t1))
 
     def _async_inputs(self, dec: list, grants: list):
         """The async step's device inputs (decode mask, page-table rows and
@@ -1734,14 +1772,17 @@ class StemEngine:
             overlapped = bool(self._inflight)
             t0 = time.perf_counter()
             dec_ids = chunk_ids = None
+            moe_counts = infl.moe_counts
             with _span("engine.wait"):
                 if infl.dec:
-                    dec_ids = np.asarray(infl.dec_ids)
+                    dec_ids, moe_counts = self._fetch(infl.dec_ids,
+                                                      moe_counts)
                     if self.smesh is not None:
                         dec_ids = dec_ids.reshape(-1)
                     self.stats["id_fetches"] += 1
                 if infl.chunks:
-                    chunk_ids = np.asarray(infl.chunk_ids)
+                    chunk_ids, moe_counts = self._fetch(infl.chunk_ids,
+                                                        moe_counts)
                     if self.smesh is None:
                         chunk_ids = chunk_ids[None]     # (1, L)
                     self.stats["id_fetches"] += 1

@@ -12,8 +12,8 @@ restored request resumes decode (or mid-prefill chunking) with **zero
 recompute** — no prefill replay, no summary rebuild, no extra traces.
 
 Both ``gather_pages`` and ``scatter_pages`` operate on the engine's
-per-layer pool tree (``PagePool`` leaves stacked ``(n_layers, hk, P, ...)``)
-with a fixed-width ``(max_pages_per_slot,)`` page-id row padded with the
+per-layer pool tree (``PagePool`` or ``LatentPool`` leaves stacked
+``(n_layers, hk, P, ...)``) with a fixed-width ``(max_pages_per_slot,)`` page-id row padded with the
 trash page, so the engine jits each exactly once
 (``launch/steps.make_page_extract`` / ``make_page_restore``).  Padding
 slots gather/scatter the trash page, which holds garbage by design.
@@ -33,30 +33,17 @@ from typing import Optional
 import jax
 import numpy as np
 
-from repro.runtime import paged as paged_lib
-
-
-def _is_pool(x) -> bool:
-    return isinstance(x, paged_lib.PagePool)
-
 
 def gather_pages(pools, page_row):
     """Extract the pages named by ``page_row`` from every layer's pool.
 
-    pools: the engine pool tree, PagePool leaves stacked (n, hk, P, ...).
+    pools: the engine pool tree, PagePool or LatentPool leaves stacked
+    (n, hk, P, ...): every leaf holds its pages on axis 2.
     page_row: (max_pages_per_slot,) int32 global page ids, trash-padded.
     Returns the same tree shape with the page axis narrowed to the row
     width — the device-side snapshot (copy to host with ``to_host``).
     """
-    def one(pool: paged_lib.PagePool) -> paged_lib.PagePool:
-        return paged_lib.PagePool(
-            k=pool.k[:, :, page_row],
-            v=pool.v[:, :, page_row],
-            kg=pool.kg[:, :, page_row],
-            vm=pool.vm[:, :, page_row],
-        )
-
-    return jax.tree.map(one, pools, is_leaf=_is_pool)
+    return jax.tree.map(lambda leaf: leaf[:, :, page_row], pools)
 
 
 def scatter_pages(pools, page_row, snapshot):
@@ -67,16 +54,8 @@ def scatter_pages(pools, page_row, snapshot):
     gathered from — the engine's page-table row carries the new mapping.
     Trash-padding slots rewrite page 0 (garbage by design, harmless).
     """
-    def one(pool: paged_lib.PagePool,
-            snap: paged_lib.PagePool) -> paged_lib.PagePool:
-        return paged_lib.PagePool(
-            k=pool.k.at[:, :, page_row].set(snap.k),
-            v=pool.v.at[:, :, page_row].set(snap.v),
-            kg=pool.kg.at[:, :, page_row].set(snap.kg),
-            vm=pool.vm.at[:, :, page_row].set(snap.vm),
-        )
-
-    return jax.tree.map(one, pools, snapshot, is_leaf=_is_pool)
+    return jax.tree.map(lambda leaf, snap: leaf.at[:, :, page_row].set(snap),
+                        pools, snapshot)
 
 
 def snapshot_nbytes(snapshot) -> int:

@@ -67,6 +67,39 @@ class PagePool(NamedTuple):
     vm: jnp.ndarray   # ([n,] hk, P) fp32 max-pooled log ||V||
 
 
+class LatentPool(NamedTuple):
+    """Paged MLA latent cache: one latent "KV head" per token, the
+    compressed ``c_kv`` (after ``kv_a_layernorm``) and the rotary key
+    ``k_rope`` side by side, plus Stem summaries of that latent.  Every
+    query head attends it through absorbed projections
+    (``models/mla.py``), so a page is stored, scored and read once for all
+    heads.  Leaves keep a head axis of 1 so pages sit on the same axis as
+    in ``PagePool`` and every page-management path serves both."""
+
+    kv: jnp.ndarray   # ([n,] 1, P, page, W) c_kv | k_rope | zeros
+    kg: jnp.ndarray   # ([n,] 1, P, stride, W) fp32 anti-diag group means
+    vm: jnp.ndarray   # ([n,] 1, P) fp32 max-pooled log ||c_kv||
+
+
+POOL_TYPES = (PagePool, LatentPool)
+
+
+def is_pool(x) -> bool:
+    return isinstance(x, POOL_TYPES)
+
+
+def _pristine(field: str):
+    """A fresh page's value in each pool field: zeros, the vm norm floor."""
+    return decode_lib.V_MAG_FLOOR if field == "vm" else 0
+
+
+def _map_pools(fn, pools):
+    """Rebuild every pool of a pool tree from ``fn(field, leaf)``."""
+    def one(pool):
+        return type(pool)(*(fn(f, leaf) for f, leaf in zip(pool._fields, pool)))
+    return jax.tree.map(one, pools, is_leaf=is_pool)
+
+
 def stack_layer(pool: PagePool) -> PagePool:
     """One layer's pool as a one-layer stack (address it with layer 0)."""
     return jax.tree.map(lambda t: t[None], pool)
@@ -97,6 +130,15 @@ def init_pool(num_pages: int, num_kv_heads: int, page_size: int, head_dim: int,
         v=jnp.zeros((hk, p, page_size, head_dim), dtype),
         kg=jnp.zeros((hk, p, stride, head_dim), jnp.float32),
         vm=jnp.full((hk, p), decode_lib.V_MAG_FLOOR, jnp.float32),
+    )
+
+
+def init_latent_pool(num_pages: int, page_size: int, width: int, stride: int,
+                     dtype=jnp.float32) -> LatentPool:
+    return LatentPool(
+        kv=jnp.zeros((1, num_pages, page_size, width), dtype),
+        kg=jnp.zeros((1, num_pages, stride, width), jnp.float32),
+        vm=jnp.full((1, num_pages), decode_lib.V_MAG_FLOOR, jnp.float32),
     )
 
 
@@ -146,22 +188,14 @@ def write_prefill_pages(pool: PagePool, page_ids: jnp.ndarray,
 
 
 def reset_pools_stacked(pools, page_ids: jnp.ndarray):
-    """``reset_pages`` over the engine's per-layer pool tree (PagePool
-    leaves stacked ``(n_layers, hk, P, ...)``).  Runs once per admission in
+    """``reset_pages`` over the engine's per-layer pool tree (PagePool or
+    LatentPool leaves stacked ``(n_layers, hk, P, ...)``).  Runs once per admission in
     the chunked engine: chunk writes fully rewrite the prompt pages, but the
     decode-spill pages and the chunk grid's overrun pages must start
     pristine (the allocator recycles pages dirty, and ``append_token``'s
-    kg-add / vm-max increments assume fresh pages)."""
-    def one(pool: PagePool) -> PagePool:
-        return PagePool(
-            k=pool.k.at[:, :, page_ids].set(0),
-            v=pool.v.at[:, :, page_ids].set(0),
-            kg=pool.kg.at[:, :, page_ids].set(0),
-            vm=pool.vm.at[:, :, page_ids].set(decode_lib.V_MAG_FLOOR),
-        )
-
-    return jax.tree.map(one, pools,
-                        is_leaf=lambda x: isinstance(x, PagePool))
+    kg-add / vm-max increments assume fresh pages).  Latent pools alike."""
+    return _map_pools(
+        lambda f, leaf: leaf.at[:, :, page_ids].set(_pristine(f)), pools)
 
 
 def copy_pages_stacked(pools, src: jnp.ndarray, dst: jnp.ndarray):
@@ -172,16 +206,8 @@ def copy_pages_stacked(pools, src: jnp.ndarray, dst: jnp.ndarray):
     writer's view is unchanged while other tenants keep the original.
 
     src, dst: scalar global page ids (static or traced int32)."""
-    def one(pool: PagePool) -> PagePool:
-        return PagePool(
-            k=pool.k.at[:, :, dst].set(pool.k[:, :, src]),
-            v=pool.v.at[:, :, dst].set(pool.v[:, :, src]),
-            kg=pool.kg.at[:, :, dst].set(pool.kg[:, :, src]),
-            vm=pool.vm.at[:, :, dst].set(pool.vm[:, :, src]),
-        )
-
-    return jax.tree.map(one, pools,
-                        is_leaf=lambda x: isinstance(x, PagePool))
+    return _map_pools(
+        lambda f, leaf: leaf.at[:, :, dst].set(leaf[:, :, src]), pools)
 
 
 def prefix_page_keys(tokens, budgets, page_size: int) -> list:
@@ -239,6 +265,20 @@ def write_chunk_pages(pool: PagePool, page_table: jnp.ndarray,
     layer: None for one layer's pool, else the (traced) layer of a stacked
     pool to write in place at ``[layer, head, page]``.
     """
+    idx, kp, vp, kg, vm = _chunk_pages(page_table, chunk_start, k_chunk,
+                                       v_chunk, true_len, cfg, layer)
+    return PagePool(
+        k=pool.k.at[idx].set(kp.astype(pool.k.dtype)),
+        v=pool.v.at[idx].set(vp.astype(pool.v.dtype)),
+        kg=pool.kg.at[idx].set(kg.astype(jnp.float32)),
+        vm=pool.vm.at[idx].set(vm.astype(jnp.float32)),
+    )
+
+
+def _chunk_pages(page_table, chunk_start, k_chunk, v_chunk, true_len, cfg,
+                 layer):
+    """The pool index of every page a chunk writes and the page contents:
+    (idx, k pages, v pages, kg, vm), each ``(hk, slots * nc, ...)``."""
     cfg = policy_lib.as_policy(cfg)
     slots, hk, c, d = k_chunk.shape
     bs = cfg.block_size
@@ -250,7 +290,7 @@ def write_chunk_pages(pool: PagePool, page_table: jnp.ndarray,
     kg = metric_lib.antidiag_pool(k, bs, cfg.stride)      # (slots, hk, nc, s, d)
     vm = metric_lib.value_block_magnitude(v, bs)          # (slots, hk, nc)
     kp = k.reshape(slots, hk, nc, bs, d)
-    vp = v.reshape(slots, hk, nc, bs, d)
+    vp = v.reshape(slots, hk, nc, bs, v.shape[-1])
 
     maxp = page_table.shape[1]
     j_abs = chunk_start[:, None] // bs + jnp.arange(nc)[None, :]  # (slots, nc)
@@ -267,11 +307,24 @@ def write_chunk_pages(pool: PagePool, page_table: jnp.ndarray,
         # (slots, hk, nc, ...) -> (hk, slots*nc, ...) aligned with ``idx``.
         return jnp.swapaxes(x, 0, 1).reshape((hk, slots * nc) + x.shape[3:])
 
-    return PagePool(
-        k=pool.k.at[idx].set(per_head(kp).astype(pool.k.dtype)),
-        v=pool.v.at[idx].set(per_head(vp).astype(pool.v.dtype)),
-        kg=pool.kg.at[idx].set(per_head(kg).astype(jnp.float32)),
-        vm=pool.vm.at[idx].set(per_head(vm).astype(jnp.float32)),
+    return idx, per_head(kp), per_head(vp), per_head(kg), per_head(vm)
+
+
+@jax.named_scope("stem.kv_write")
+def write_latent_chunk(pool: LatentPool, page_table: jnp.ndarray,
+                       chunk_start: jnp.ndarray, lat: jnp.ndarray,
+                       true_len: jnp.ndarray, rank: int, cfg,
+                       layer=None) -> LatentPool:
+    """``write_chunk_pages`` for a latent pool: lat (slots, C, W) is the
+    chunk's latent, its first ``rank`` entries ``c_kv`` (the value whose
+    norm ``vm`` pools)."""
+    k = lat[:, None]
+    idx, kp, _, kg, vm = _chunk_pages(page_table, chunk_start, k,
+                                      k[..., :rank], true_len, cfg, layer)
+    return LatentPool(
+        kv=pool.kv.at[idx].set(kp.astype(pool.kv.dtype)),
+        kg=pool.kg.at[idx].set(kg.astype(jnp.float32)),
+        vm=pool.vm.at[idx].set(vm.astype(jnp.float32)),
     )
 
 
@@ -295,6 +348,20 @@ def append_token(pool: PagePool, page_table: jnp.ndarray,
     layer's pool, else the (traced) layer of a stacked pool to write in
     place at ``[layer, head, page, offset]``.
     """
+    page, at, group, knh, vnh, kg_add, log_norm = _token_slots(
+        page_table, cache_lens, k_new, v_new, cfg, layer)
+    return PagePool(
+        k=pool.k.at[at].set(knh.astype(pool.k.dtype)),
+        v=pool.v.at[at].set(vnh.astype(pool.v.dtype)),
+        kg=pool.kg.at[group].add(kg_add),
+        vm=pool.vm.at[page].max(log_norm),
+    )
+
+
+def _token_slots(page_table, cache_lens, k_new, v_new, cfg, layer):
+    """Where one new token per slot lands and what it adds: (page index,
+    token index, summary-group index, k rows, v rows, kg increment, log
+    ||v||), rows ``(hk, slots, ...)``."""
     cfg = policy_lib.as_policy(cfg)
     hk = k_new.shape[1]
     bs, stride = cfg.block_size, cfg.stride
@@ -302,18 +369,27 @@ def append_token(pool: PagePool, page_table: jnp.ndarray,
     lens = jnp.asarray(cache_lens, jnp.int32)
     pids = jnp.take_along_axis(page_table, (lens // bs)[:, None], axis=1)[:, 0]
     offs = lens % bs
-    kn = k_new[:, :, 0]                                     # (slots, hk, d)
-    vn = v_new[:, :, 0]
-    knh = jnp.swapaxes(kn, 0, 1)                            # (hk, slots, d)
-    vnh = jnp.swapaxes(vn, 0, 1)
+    knh = jnp.swapaxes(k_new[:, :, 0], 0, 1)                # (hk, slots, d)
+    vnh = jnp.swapaxes(v_new[:, :, 0], 0, 1)
     log_norm = jnp.log(jnp.maximum(
         jnp.linalg.norm(vnh.astype(jnp.float32), axis=-1), 1e-20))
     page = _page_index(layer, hk, pids)                     # (hk, slots)
-    return PagePool(
-        k=pool.k.at[page + (offs,)].set(knh.astype(pool.k.dtype)),
-        v=pool.v.at[page + (offs,)].set(vnh.astype(pool.v.dtype)),
-        kg=pool.kg.at[page + (offs % stride,)].add(
-            (knh / per_group).astype(jnp.float32)),
+    return (page, page + (offs,), page + (offs % stride,), knh, vnh,
+            (knh / per_group).astype(jnp.float32), log_norm)
+
+
+@jax.named_scope("stem.kv_write")
+def append_latent(pool: LatentPool, page_table: jnp.ndarray,
+                  cache_lens: jnp.ndarray, lat: jnp.ndarray, rank: int, cfg,
+                  layer=None) -> LatentPool:
+    """``append_token`` for a latent pool: lat (slots, 1, W), the new
+    token's latent, its first ``rank`` entries ``c_kv``."""
+    k = lat[:, None]
+    page, at, group, knh, _, kg_add, log_norm = _token_slots(
+        page_table, cache_lens, k, k[..., :rank], cfg, layer)
+    return LatentPool(
+        kv=pool.kv.at[at].set(knh.astype(pool.kv.dtype)),
+        kg=pool.kg.at[group].add(kg_add),
         vm=pool.vm.at[page].max(log_norm),
     )
 
@@ -405,6 +481,69 @@ def _paged_decode_xla(
     with jax.named_scope("stem.attend"):
         gk, gv = gather_pages(pools, layer, gp)             # (b,hk,g,kmax,bs,d)
         return decode_lib.attend_selected(q, gk, gv, sel, cache_lens, bs)
+
+
+def _latent_pages(pools: LatentPool, layer, gp: jnp.ndarray) -> jnp.ndarray:
+    """Kept latent pages of one layer, read once per slot: gp (b, ...)
+    global page ids -> (b, ..., page, W)."""
+    return pools.kv[layer, 0, gp]
+
+
+def paged_latent_decode(qt: jnp.ndarray, pool: LatentPool,
+                        page_table: jnp.ndarray, cache_lens: jnp.ndarray,
+                        rank: int, cfg,
+                        budget_frac: float = decode_lib.DEFAULT_BUDGET_FRAC,
+                        layer=None) -> jnp.ndarray:
+    """Stem decode over latent pages, absorbed.  qt (slots, h, 1, W): each
+    head's ``q_nope W_UK`` beside its ``q_rope`` (zero-padded as the
+    latent), scaled so that ``qt . latent / sqrt(W)`` is the head's softmax
+    logit.  The
+    heads' page scores are reduced over the heads
+    (``policy.latent_group_reduce``), so a slot keeps one page set per
+    layer and gathers each kept page once.  Returns the attention output in
+    the latent, (slots, h, 1, rank) float32 (``W_UV`` comes after)."""
+    cfg = policy_lib.as_policy(cfg)
+    if layer is None:
+        pool, layer = stack_layer(pool), 0
+    with jax.named_scope("stem.score"):
+        kg_rows, vm_rows = gather_summaries(pool, layer, page_table)
+        m = cfg.decode_scores(qt, kg_rows, vm_rows)          # (b, 1, h, P)
+    with jax.named_scope("stem.select"):
+        m = policy_lib.share_heads(m, 2, policy_lib.latent_group_reduce(cfg))
+        sel = cfg.decode_select(m, cache_lens, budget_frac=budget_frac)
+        gp = jnp.take_along_axis(page_table, sel.indices[:, 0, 0], axis=1)
+    with jax.named_scope("stem.attend"), jax.named_scope("stem.mla_decode"):
+        return decode_lib.attend_latent(qt, _latent_pages(pool, layer, gp),
+                                        sel, cache_lens, cfg.block_size, rank)
+
+
+def latent_chunk_attention(qt: jnp.ndarray, pool: LatentPool,
+                           page_table: jnp.ndarray, chunk_start: jnp.ndarray,
+                           budgets: jnp.ndarray, rank: int, cfg,
+                           k_max: int = 0, layer=None) -> jnp.ndarray:
+    """Stem prefill of one chunk per lane over latent pages, absorbed: qt
+    (b, h, C, W) as in ``paged_latent_decode``; the chunk's own
+    pages are already written.  Each query-block row keeps one page set for
+    all heads (scores reduced over heads) and reads it once.  Returns (b,
+    h, C, rank) float32."""
+    cfg = policy_lib.as_policy(cfg)
+    if layer is None:
+        pool, layer = stack_layer(pool), 0
+    b, h, c, _ = qt.shape
+    bs = cfg.block_size
+    nc = c // bs
+    with jax.named_scope("stem.score"):
+        kg_rows, vm_rows = gather_summaries(pool, layer, page_table)
+        m = cfg.chunk_scores(qt, kg_rows, vm_rows)           # (b, h, nc, P)
+    with jax.named_scope("stem.select"):
+        m = policy_lib.share_heads(m, 1, policy_lib.latent_group_reduce(cfg))
+        rows = chunk_start[:, None] // bs + jnp.arange(nc)[None, :]
+        sel = chunked_lib.select_chunk_blocks(m, rows, budgets, cfg, k_max)
+        gp = jnp.take_along_axis(page_table[:, None, :],
+                                 sel.indices[:, 0], axis=-1)  # (b, nc, kmax)
+    with jax.named_scope("stem.attend"), jax.named_scope("stem.mla_chunk"):
+        return chunked_lib.attend_latent_chunk(
+            qt, _latent_pages(pool, layer, gp), sel, chunk_start, bs, rank)
 
 
 # The gather oracle is the registry's "xla" backend for both serving lanes

@@ -117,6 +117,15 @@ def shard_pools(pools, smesh: ServingMesh):
     them: dp slot groups each get a full pool copy, KV-head axis sharded
     over tp.  All groups start from the same pristine pool, so group 0 of
     a dp>1 engine is bit-identical to a single-device pool."""
+    from repro.runtime import paged as paged_lib
+
+    latent = any(isinstance(p, paged_lib.LatentPool)
+                 for p in jax.tree.leaves(pools, is_leaf=paged_lib.is_pool))
+    if latent and smesh.tp > 1:
+        raise NotImplementedError(
+            f"latent (MLA) page pools hold one latent head per token and "
+            f"cannot be split over tp={smesh.tp}; serve MLA with tp=1 "
+            "(dp slot groups only)")
     sh = pool_sharding(smesh)
 
     def place(leaf):

@@ -93,7 +93,7 @@ def _chunked(params, cfg, pol, prompt, page_row, num_pages, chunk):
               "budgets": jnp.asarray(cbud),
               "last": jnp.asarray([min(max(plen - 1 - t0, 0), chunk - 1)],
                                   jnp.int32)}
-        _, logits, pools = transformer.paged_mixed_step(
+        _, logits, pools, _ = transformer.paged_mixed_step(
             params, dec_tokens, pools, dec_table, dec_lens, cfg,
             stem_cfg=pol, budget_frac=1.0, chunk=cd, chunk_k_max=k_bound)
     return np.asarray(logits)[0], pools

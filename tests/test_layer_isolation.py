@@ -133,7 +133,7 @@ def test_layers_write_only_their_own_pool(executor, mixed):
                        budget_frac=BUDGET, chunk=chunk,
                        chunk_k_max=chunked_lib.chunk_budget_bound(POL, MAXP),
                        executor=executor))
-    dec, chunk_logits, out = step(params, tokens, pools, chunk)
+    dec, chunk_logits, out, _ = step(params, tokens, pools, chunk)
     ref_dec, ref_chunk, ref = jax.jit(
         lambda params, tokens, pools, chunk: _reference_step(
             params, tokens, pools, chunk, executor))(

@@ -155,8 +155,9 @@ UNIFIED_CASES = [("xla", "sync"), ("pallas", "sync"), ("pallas", "async")]
 
 
 def _unified_step(spec, monkeypatch, executor, loop, *, layers, slots,
-                  max_prompt, max_new_tokens, donate=False):
-    """The engine's unified step at qwen3-0.6b width with the depth cut to
+                  max_prompt, max_new_tokens, donate=False,
+                  arch="qwen3-0.6b"):
+    """The engine's unified step at ``arch``'s width with the depth cut to
     ``layers``, jitted for the described chip, and its abstract arguments
     for both signatures: ``(step, args of the mixed step, args of the
     decode-only step, the abstract pools)``.  ``donate`` donates the pools
@@ -165,7 +166,7 @@ def _unified_step(spec, monkeypatch, executor, loop, *, layers, slots,
     to Mosaic here."""
     if executor == "pallas":
         monkeypatch.setattr(backend, "interpret_kernels", lambda: False)
-    cfg = configs.get_config("qwen3-0.6b").replace(num_layers=layers)
+    cfg = configs.get_config(arch).replace(num_layers=layers)
     bundle = registry.build(cfg)
     pol = serving_policy("stem", BS)
     ecfg = EngineConfig.for_trace(max_slots=slots, max_prompt=max_prompt,
@@ -267,3 +268,36 @@ def test_unified_step_updates_pools_in_place(spec, monkeypatch, executor,
         mem = compiled.memory_analysis()
         assert mem.temp_size_in_bytes < pool_bytes
         assert mem.alias_size_in_bytes >= pool_bytes
+
+
+def test_latent_step_updates_pools_in_place(spec, monkeypatch):
+    """deepseek-v2-lite's unified step (MLA over latent pages, dropless
+    experts) at its published widths with the depth cut to 3 (the dense
+    layer and two MoE layers), pools donated: no op copies, slices or
+    restacks a latent pool leaf (kv, kg, vm; stacked or one layer's), the
+    step's scratch stays below the pools' bytes, and the mixed step's ops
+    carry the ``stem.mla_decode``, ``stem.mla_chunk`` and ``stem.moe``
+    phases.  Pools of 2.2 GB in the benchmark cell's geometry (8 slots x
+    516 pages of 64k-token requests); at 4 slots the compiler copies the
+    stack around the decode write instead.  The ``vm`` leaf (one float a
+    page) is left out: XLA relays out its unit head axis with a copy of
+    that size."""
+    step, mixed, decode, pools = _unified_step(
+        spec, monkeypatch, "xla", "sync", layers=3, slots=8,
+        max_prompt=65536, max_new_tokens=512, donate=True,
+        arch="deepseek-v2-lite")
+    leaves = jax.tree.leaves(pools)
+    assert leaves[0].shape[-1] == 640          # 512 c_kv + 64 k_rope, padded
+    big = [l for l in leaves if l.ndim > 3]    # kv and kg, not vm
+    shapes = {l.shape for l in big} | {l.shape[1:] for l in big}
+    pool_bytes = sum(l.size * l.dtype.itemsize for l in leaves)
+    for args in (mixed, decode):
+        compiled = step.lower(*args).compile()
+        hlo = compiled.as_text()
+        assert not _pool_plumbing(hlo, shapes)
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < pool_bytes
+        assert mem.alias_size_in_bytes >= pool_bytes
+        phases = ("stem.mla_decode", "stem.moe") + (
+            ("stem.mla_chunk",) if args is mixed else ())
+        assert all(p in hlo for p in phases)
