@@ -165,7 +165,11 @@ def attend_chunk(
     block_size: int,
 ) -> jnp.ndarray:
     """Masked softmax over the selected pages only, token-causal at
-    absolute positions.  Returns (b, hq, C, dv)."""
+    absolute positions.  Both products take the gathered pages in their
+    stored dtype with float32 accumulation, q and the probabilities
+    entering at the pages' dtype (what a default-precision matrix product
+    takes on the TPU); masking and the softmax stay in float32, and no
+    page is copied or cast.  Returns (b, hq, C, dv)."""
     b, hq, c, d = q.shape
     hk = gk.shape[1]
     group = hq // hk
@@ -173,8 +177,9 @@ def attend_chunk(
     nc = c // bs
     k_max = gk.shape[4]
     dv = gv.shape[-1]
-    qg = q.reshape(b, hk, group, nc, bs, d).astype(jnp.float32)
-    s = jnp.einsum("bhgnqd,bhgnkcd->bhgnqkc", qg, gk.astype(jnp.float32))
+    qg = q.reshape(b, hk, group, nc, bs, d).astype(gk.dtype)
+    s = jnp.einsum("bhgnqd,bhgnkcd->bhgnqkc", qg, gk,
+                   preferred_element_type=jnp.float32)
     s = s * (d ** -0.5)                         # (b, hk, g, nc, bs_q, kmax, bs_k)
     live = sel.live.reshape(b, hk, group, nc, k_max)
     tok_pos = sel.indices.reshape(b, hk, group, nc, k_max)[..., None] * bs \
@@ -187,7 +192,8 @@ def attend_chunk(
     s = jnp.where(keep, s, NEG_INF)
     p = jax.nn.softmax(s.reshape(b, hk, group, nc, bs, -1), axis=-1)
     p = jnp.where(keep, p.reshape(s.shape), 0.0)
-    o = jnp.einsum("bhgnqkc,bhgnkcd->bhgnqd", p, gv.astype(jnp.float32))
+    o = jnp.einsum("bhgnqkc,bhgnkcd->bhgnqd", p.astype(gv.dtype), gv,
+                   preferred_element_type=jnp.float32)
     return o.reshape(b, hq, c, dv).astype(q.dtype)
 
 

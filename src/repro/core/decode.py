@@ -143,6 +143,12 @@ def debug_assert_live_rows(sel: DecodeSelection,
 # Stage 3: exact attention over gathered blocks
 # ---------------------------------------------------------------------------
 
+def _pair_diagonal(x: jnp.ndarray) -> jnp.ndarray:
+    """(b, t, r, r, ...) products of every row of a head pair with both
+    heads' pages -> (b, t, r, ...), each row's product with its own."""
+    return jnp.moveaxis(jnp.diagonal(x, axis1=2, axis2=3), -1, 2)
+
+
 def attend_selected(
     q: jnp.ndarray,            # (b, hq, 1, d)
     gk: jnp.ndarray,           # (b, hk, g, k_max, bs, d) gathered key blocks
@@ -152,6 +158,16 @@ def attend_selected(
     block_size: int,
 ) -> jnp.ndarray:
     """Masked softmax over the selected blocks only.  Returns (b, hq, 1, dv).
+
+    **Dtypes:** both products take the gathered pages in their stored
+    dtype with float32 accumulation, q and the probabilities entering at
+    the pages' dtype; masking and the softmax stay in float32, and no page
+    is copied or cast.  A product with one query row compiles on the TPU
+    to a multiply-reduce that first widens its whole page operand to
+    float32 in an op of its own, so query heads go in pairs (alone when
+    their count is odd): each pair's two rows meet both heads' pages in
+    one matrix product, and each row keeps its product with its own
+    head's pages.
 
     **Zero-live-row contract:** a row whose selection carries no live slot
     (``sel.live`` all False — e.g. ``cache_lens == 0`` trash slots riding in
@@ -168,20 +184,26 @@ def attend_selected(
     """
     debug_assert_live_rows(sel, context="attend_selected")
     b, hq, _, d = q.shape
-    hk = gk.shape[1]
-    group = hq // hk
-    bs = block_size
+    hk, group, k_max, bs, _ = gk.shape[1:]
+    dv = gv.shape[-1]
+    pair = 2 if hq % 2 == 0 else 1
+    t = hq // pair
     cache_lens = jnp.broadcast_to(jnp.asarray(cache_lens, jnp.int32), (b,))
-    qg = q.reshape(b, hk, group, 1, d).astype(jnp.float32)
-    s = jnp.einsum("bhgqd,bhgnkd->bhgqnk", qg, gk.astype(jnp.float32))
-    s = s * (d ** -0.5)                                    # (b,hk,g,1,kmax,bs)
+    qp = q.reshape(b, t, pair, d).astype(gk.dtype)
+    s = jnp.einsum("btrd,btRnkd->btrRnk", qp,
+                   gk.reshape(b, t, pair, k_max, bs, d),
+                   preferred_element_type=jnp.float32)
+    s = _pair_diagonal(s).reshape(b, hk, group, 1, k_max, bs) * (d ** -0.5)
     tok_pos = sel.indices[..., None] * bs + jnp.arange(bs)  # (b,hk,g,kmax,bs)
     keep = (tok_pos < cache_lens[:, None, None, None, None]) & sel.live[..., None]
     s = jnp.where(keep[:, :, :, None], s, NEG_INF)
     p = jax.nn.softmax(s.reshape(b, hk, group, 1, -1), axis=-1).reshape(s.shape)
     p = jnp.where(keep[:, :, :, None], p, 0.0)
-    o = jnp.einsum("bhgqnk,bhgnkd->bhgqd", p, gv.astype(jnp.float32))
-    return o.reshape(b, hq, 1, gv.shape[-1]).astype(q.dtype)
+    o = jnp.einsum("btrnk,btRnkd->btrRd",
+                   p.reshape(b, t, pair, k_max, bs).astype(gv.dtype),
+                   gv.reshape(b, t, pair, k_max, bs, dv),
+                   preferred_element_type=jnp.float32)
+    return _pair_diagonal(o).reshape(b, hq, 1, dv).astype(q.dtype)
 
 
 def attend_latent(

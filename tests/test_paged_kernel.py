@@ -17,6 +17,8 @@ antidiag/mean metric pooling, group_reduce none/mean, and the streaming
 (TestZeroLiveRows — referenced from ``core/decode.attend_selected``) and
 the REPRO_DEBUG_DECODE assert.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -331,3 +333,164 @@ class TestDebugAssert:
             q, pool, pt, jnp.asarray([15, 0], jnp.int32), pol, 0.25,
             executor="pallas")
         jax.block_until_ready(out)  # no raise
+
+
+# ---------------------------------------------------------------------------
+# Attention over gathered pages in their stored dtype
+# ---------------------------------------------------------------------------
+
+def _f32_attend(q, k, v, keep):
+    """The float32-cast formula: q (..., nq, d), k and v (..., nk, d) cast
+    to float32, masked softmax over ``keep`` (..., nq, nk); a query row
+    with nothing kept gives zero."""
+    q, k, v = (np.asarray(x, np.float32) for x in (q, k, v))
+    s = q @ np.swapaxes(k, -1, -2) / np.float32(np.sqrt(q.shape[-1]))
+    s = np.where(keep, s, -np.inf)
+    m = np.max(s, axis=-1, keepdims=True)
+    e = np.where(keep, np.exp(s - np.where(np.isfinite(m), m, 0)), 0)
+    z = np.sum(e, axis=-1, keepdims=True)
+    return (e / np.where(z > 0, z, 1)) @ v
+
+
+def _bf16(rng, *shape):
+    return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+
+# Output error allowed against the float32-cast formula, per element:
+# one bf16 rounding of the output (2^-9 of it) and of the probabilities
+# (2^-9 of the largest |v| they weigh).
+def _bf16_tol(ref, v):
+    return 2 ** -9 * (np.abs(ref) + np.max(np.abs(np.asarray(v, np.float32))))
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("hq,group", [(HQ, 1), (HQ, 2), (3, 1)])
+def test_attend_selected_bf16_pages_match_f32_formula(hq, group, seed):
+    """Decode lane over bf16 pages == the float32-cast formula up to bf16
+    rounding of the output: dead slots, a partial last page, and a row
+    with no live slot (exactly zero); query heads in pairs, or alone
+    when their count is odd."""
+    rng = np.random.default_rng(seed)
+    b, hk, k_max, nblk, d = 3, hq // group, 3, 5, 16
+    q = _bf16(rng, b, hq, 1, d)
+    gk = _bf16(rng, b, hk, group, k_max, BS, d)
+    gv = _bf16(rng, b, hk, group, k_max, BS, d)
+    lens = np.asarray([nblk * BS - 3, 2 * BS + 1, 0], np.int32)
+    idx = np.stack([rng.permutation(nblk)[:k_max]
+                    for _ in range(b * hk * group)]).reshape(
+                        b, hk, group, k_max)
+    live = rng.random(idx.shape) < 0.7                  # dead slots
+    live[0, :, :, 0], idx[0, :, :, 0] = True, nblk - 1  # the partial page
+    live[1, 0, 0] = False      # a head of a non-empty row with none live
+    live[2] = False                                     # the empty row
+    sel = decode_lib.DecodeSelection(
+        indices=jnp.asarray(idx, jnp.int32), live=jnp.asarray(live),
+        budgets=jnp.full((b,), k_max, jnp.int32),
+        n_valid=jnp.asarray(-(-lens // BS), jnp.int32))
+    out = decode_lib.attend_selected(q, gk, gv, sel, jnp.asarray(lens), BS)
+    assert out.dtype == jnp.bfloat16
+
+    pos = idx[..., None] * BS + np.arange(BS)           # (b, hk, g, k, BS)
+    keep = (pos < lens[:, None, None, None, None]) & live[..., None]
+    ref = _f32_attend(
+        np.asarray(q, np.float32).reshape(b, hk, group, 1, d),
+        np.asarray(gk, np.float32).reshape(b, hk, group, k_max * BS, d),
+        np.asarray(gv, np.float32).reshape(b, hk, group, k_max * BS, d),
+        keep.reshape(b, hk, group, 1, k_max * BS)).reshape(b, hq, 1, d)
+    got = np.asarray(out, np.float32)
+    assert np.all(np.abs(got - ref) <= _bf16_tol(ref, gv))
+    assert np.all(got[2] == 0.0) and np.all(got[1, :1] == 0.0)
+    assert np.all(np.abs(got[0]).max(axis=-1) > 0)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("group", [1, 2])
+def test_attend_chunk_bf16_pages_match_f32_formula(group, seed):
+    """Chunk lane over bf16 pages == the float32-cast formula up to bf16
+    rounding of the output: dead slots, the chunk's own pages causal to
+    the token, and a query-block row with no live page (exactly zero)."""
+    rng = np.random.default_rng(seed)
+    b, hk, nc, k_max, d = 2, HQ // group, 2, 3, 16
+    hist = 2                                   # pages before the chunk
+    c = nc * BS
+    q = _bf16(rng, b, HQ, c, d)
+    gk = _bf16(rng, b, hk, group, nc, k_max, BS, d)
+    gv = _bf16(rng, b, hk, group, nc, k_max, BS, d)
+    start = np.full((b,), hist * BS, np.int32)
+    rows = hist + np.arange(nc)                 # absolute query-block rows
+    idx = np.stack([rng.permutation(rows[j] + 1)[:k_max]
+                    for _ in range(b * HQ) for j in range(nc)]).reshape(
+                        b, HQ, nc, k_max)
+    live = rng.random(idx.shape) < 0.7                  # dead slots
+    idx[..., 0] = rows                          # each row's own page ...
+    live[..., 0] = True                         # ... partly causal
+    live[1, 0, 1] = False                       # a row with nothing live
+    sel = chunked_lib.ChunkSelection(indices=jnp.asarray(idx, jnp.int32),
+                                     live=jnp.asarray(live))
+    out = chunked_lib.attend_chunk(q, gk, gv, sel, jnp.asarray(start), BS)
+    assert out.dtype == jnp.bfloat16
+
+    tok = (idx[..., None] * BS + np.arange(BS)).reshape(b, HQ, nc, 1, -1)
+    qpos = (start[:, None] + np.arange(c)).reshape(b, 1, nc, BS, 1)
+    keep = (tok <= qpos) & np.repeat(live, BS, axis=-1)[:, :, :, None]
+    as_heads = lambda x: np.asarray(x, np.float32).reshape(
+        b, HQ, nc, k_max * BS, d)
+    ref = _f32_attend(np.asarray(q, np.float32).reshape(b, HQ, nc, BS, d),
+                      as_heads(gk), as_heads(gv), keep).reshape(b, HQ, c, d)
+    got = np.asarray(out, np.float32)
+    assert np.all(np.abs(got - ref) <= _bf16_tol(ref, gv))
+    assert np.all(got[1, 0, BS:] == 0.0)
+    assert np.all(np.abs(got[0]).max(axis=-1) > 0)
+
+
+_STABLEHLO = re.compile(r"stablehlo\.(\w+)\"?[^\n]*-> tensor<([\dx]*)x(\w+)>$")
+
+
+def _page_copies_cast_to_f32(text: str, page_elems: int) -> list:
+    """``convert``s of a lowered module whose f32 result holds as many
+    elements as one gathered page copy."""
+    found = []
+    for line in text.splitlines():
+        m = _STABLEHLO.search(line.strip())
+        if m and m.group(1) == "convert" and m.group(3) == "f32":
+            dims = [int(x) for x in m.group(2).split("x") if x]
+            if int(np.prod(dims)) == page_elems:
+                found.append(line.strip())
+    return found
+
+
+@pytest.mark.parametrize("lane", ["decode", "chunk"])
+@pytest.mark.parametrize("group", [1, 2])
+def test_xla_executor_casts_no_gathered_page(lane, group):
+    """The xla executor, lowered with bf16 pools, converts no gathered K/V
+    page copy to float32: the pages enter the attention products as
+    stored."""
+    d, maxp, nc = 16, 6, 2              # d != BS keeps pages apart from p
+    hk = HQ // group
+    pol = _policy()
+    f = jax.ShapeDtypeStruct
+    pools = paged_lib.PagePool(
+        k=f((2, hk, 20, BS, d), jnp.bfloat16),
+        v=f((2, hk, 20, BS, d), jnp.bfloat16),
+        kg=f((2, hk, 20, STRIDE, d), jnp.float32),
+        vm=f((2, hk, 20), jnp.float32))
+    b = 3
+    layer, pt = f((), jnp.int32), f((b, maxp), jnp.int32)
+    if lane == "decode":
+        fn = lambda q, pools, layer, pt, lens: paged_lib._paged_decode_xla(
+            q, pools, layer, pt, lens, pol, 0.5)
+        args = (f((b, HQ, 1, d), jnp.bfloat16), pools, layer, pt,
+                f((b,), jnp.int32))
+        k_max = decode_lib.decode_budget_bound(maxp, pol, 0.5)
+        rows = 1
+    else:
+        fn = lambda q, pools, layer, pt, start, budgets: \
+            chunked_lib._chunked_prefill_xla(q, pools, layer, pt, start,
+                                             budgets, pol, 0)
+        args = (f((b, HQ, nc * BS, d), jnp.bfloat16), pools, layer, pt,
+                f((b,), jnp.int32), f((b, nc), jnp.int32))
+        k_max, rows = maxp, nc
+    text = jax.jit(fn).lower(*args).as_text()
+    page_elems = b * HQ * rows * k_max * BS * d
+    assert f"{b}x{hk}x{group}x" in text and "bf16>" in text
+    assert not _page_copies_cast_to_f32(text, page_elems)

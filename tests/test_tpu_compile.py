@@ -11,6 +11,7 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process may hold the TPU library at a time, and every pytest
 worker imports this file.
 """
+import math
 import os
 import re
 
@@ -301,3 +302,40 @@ def test_latent_step_updates_pools_in_place(spec, monkeypatch):
         phases = ("stem.mla_decode", "stem.moe") + (
             ("stem.mla_chunk",) if args is mixed else ())
         assert all(p in hlo for p in phases)
+
+
+def _f32_page_copies(hlo: str) -> list:
+    """Instructions of compiled ``hlo`` that write a float32 gathered page
+    copy to memory: outside fusion bodies, pages of (BS, D) as the minor
+    dims, and as many elements as one of the ``stem.attend`` gathers'
+    results."""
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", hlo))
+    pages, comp = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) ", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]+)\]", line)
+        if m and comp not in fused:
+            dims = tuple(int(x) for x in m.group(3).split(","))
+            if dims[-2:] == (BS, D):
+                pages.append((m.group(1), m.group(2), math.prod(dims),
+                              '/stem.attend/gather"' in line))
+    copies = {n for _, _, n, gather in pages if gather}
+    assert copies, "no gathered page copy found"
+    return [name for name, dtype, n, _ in pages
+            if dtype == "f32" and n in copies]
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "qwen1.5-4b"])
+def test_xla_executor_attends_pages_as_stored(spec, monkeypatch, arch):
+    """The xla executor's unified step over bf16 pools (both signatures,
+    GQA groups of 2 and 1) holds no float32 copy of a gathered page: the
+    TPU compiler widens the page operand of a one-row product in an op of
+    its own, so the decode lane must reach it as a matrix product."""
+    step, mixed, decode, _ = _unified_step(
+        spec, monkeypatch, "xla", "sync", layers=LAYERS, slots=3,
+        max_prompt=4096, max_new_tokens=256, arch=arch)
+    for args in (mixed, decode):
+        assert not _f32_page_copies(step.lower(*args).compile().as_text())
